@@ -31,6 +31,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from benchmarks.common import time_us, write_bench_json, write_rows
 from repro.kernels import kmeans_assign as _ka
@@ -41,10 +42,9 @@ BENCH = "fused_lloyd"
 
 
 def _subjaxprs(v):
-    import jax.core as jax_core
-    if isinstance(v, jax_core.ClosedJaxpr):
+    if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax_core.Jaxpr):
+    elif isinstance(v, Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for x in v:

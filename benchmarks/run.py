@@ -14,20 +14,17 @@ benchmarks/artifacts/.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
+from pathlib import Path
 
-# The paper benchmarks measure LOSS and COMMUNICATION, not kernel wall time;
-# on this CPU container the Pallas kernels run in interpret mode (~20x slower
-# than compiled jnp, semantically identical — tests/test_kernels.py proves
-# it), so route the hot loops to the jnp references.  kernel_micro and
-# fused_lloyd resolve the execution mode themselves via
-# repro.core.api.resolve_backend: compiled-kernel timings on TPU/GPU,
-# jnp-ref (+ structural census) on CPU — interpret-mode wall numbers are
-# only recorded behind their explicit --interpret flag, clearly labeled.
-os.environ.setdefault("REPRO_NO_PALLAS", "1")
+from repro.utils.compile_cache import use_compile_cache
 
+# The paper benchmarks measure LOSS and COMMUNICATION.  Every section
+# resolves its score backend through repro.core.api.resolve_backend:
+# compiled Pallas kernels on TPU, the jnp references on CPU (interpret-mode
+# wall numbers are only recorded behind kernel_micro / fused_lloyd's
+# explicit --interpret flag, clearly labeled).
 MODULES = [
     "vrlr_main",        # Table 1 left / Fig 2
     "vkmc_main",        # Table 1 right / Fig 3
@@ -62,6 +59,7 @@ def main() -> int:
                          "continuing (non-zero exit with a traceback; used "
                          "by the CI gate steps)")
     args = ap.parse_args()
+    use_compile_cache(Path(__file__).resolve().parents[1])
     if args.list:
         print("\n".join(MODULES))
         return 0

@@ -16,9 +16,6 @@ the computation runs, never what it draws.
   PYTHONPATH=src python examples/auto_plan.py
 """
 
-import os
-os.environ.setdefault("REPRO_NO_PALLAS", "1")
-
 import jax
 import numpy as np
 

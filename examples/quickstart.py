@@ -11,9 +11,6 @@ compiled call through the batched engine.
   PYTHONPATH=src python examples/quickstart.py
 """
 
-import os
-os.environ.setdefault("REPRO_NO_PALLAS", "1")   # CPU: use jnp refs for speed
-
 import jax
 import jax.numpy as jnp
 

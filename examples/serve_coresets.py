@@ -6,9 +6,6 @@ end.
   PYTHONPATH=src python examples/serve_coresets.py
 """
 
-import os
-os.environ.setdefault("REPRO_NO_PALLAS", "1")
-
 import jax
 import numpy as np
 

@@ -4,9 +4,6 @@
   PYTHONPATH=src python examples/serve_lm.py --arch llama3.2-1b --batch 4
 """
 
-import os
-os.environ.setdefault("REPRO_NO_PALLAS", "1")
-
 import argparse
 import time
 

@@ -12,9 +12,6 @@ gradient at ~fraction of the compute/communication.
   PYTHONPATH=src python examples/train_lm_coreset.py --compare   # all 3 modes
 """
 
-import os
-os.environ.setdefault("REPRO_NO_PALLAS", "1")
-
 import argparse
 import dataclasses
 import time

@@ -12,9 +12,6 @@ the ``fit_kmeans``/``evaluate`` layer (Theorem 5.2's composition).
   PYTHONPATH=src python examples/vfl_kmeans.py
 """
 
-import os
-os.environ.setdefault("REPRO_NO_PALLAS", "1")
-
 import jax
 
 from repro.core import (

@@ -10,9 +10,6 @@ downstream ridge solve + full-data relative error come from the
   PYTHONPATH=src python examples/vfl_regression.py
 """
 
-import os
-os.environ.setdefault("REPRO_NO_PALLAS", "1")
-
 import jax
 
 from repro.core import (

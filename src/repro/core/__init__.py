@@ -201,7 +201,7 @@ def build_vrlr_coreset(
 ) -> Coreset:
     """Deprecated: use ``build_coreset("vrlr", ds, m, key=key, ...)``."""
     _deprecated("build_vrlr_coreset", 'build_coreset("vrlr", ...)')
-    # use_kernel=True maps to "auto" (kernels where they profit — TPU/GPU),
+    # use_kernel=True maps to "auto" (kernels where they profit — TPU),
     # so the shim keeps resolving to the same backend as build_coreset's
     # default and stays draw-identical to it on every platform.
     return build_coreset("vrlr", ds, m, key=key,

@@ -90,13 +90,13 @@ CORESET_TASKS = Registry("coreset_task")
 def resolve_backend(backend: str) -> str:
     """Resolve ``"auto"`` to a concrete ScoreBackend for this process.
 
-    ``auto`` picks ``pallas`` on TPU/GPU (compiled kernels) and ``ref`` on
-    CPU — interpret-mode Pallas is 25-60x slower than the compiled jnp
-    references there (BENCH_kernels.json), so a silent ``pallas`` default
-    was a CPU footgun.  Explicit names pass through (and are validated).
+    ``auto`` picks ``pallas`` on TPU (compiled kernels) and ``ref``
+    everywhere else — the kernels only interpret on CPU, 25-60x slower than
+    the compiled jnp references there (BENCH_kernels.json), and refuse any
+    other backend.  Explicit names pass through (and are validated).
     """
     if backend == "auto":
-        return "pallas" if jax.default_backend() in ("tpu", "gpu") else "ref"
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
     if backend not in SCORE_BACKENDS:
         raise ValueError(
             f"unknown score backend {backend!r}; expected 'auto' or one of "
@@ -1193,8 +1193,8 @@ def build_coreset(
 
     Task-specific knobs (vkmc's ``k``/``alpha``/``local_iters``) pass through
     ``**params`` to the task's score function.  ``backend`` defaults to
-    ``"auto"`` (:func:`resolve_backend`: kernels on TPU/GPU, jnp refs on
-    CPU).  The exact per-round communication bill is derived from the
+    ``"auto"`` (:func:`resolve_backend`: kernels on TPU, jnp refs
+    elsewhere).  The exact per-round communication bill is derived from the
     realised plan and recorded on ``ledger`` (when given);
     ``Coreset.comm_units`` is always this construction's own total.
     """

@@ -72,6 +72,20 @@ def _key_chain(key: jax.Array, num: int) -> jax.Array:
     return subs
 
 
+def _gumbel_from_bits(bits, rows: int, n: int):
+    """(rows, n) float32 gumbel noise from raw uint32 uniform bits —
+    ``jax.random._uniform``'s mantissa trick and ``gumbel``'s double-log,
+    replayed verbatim."""
+    float_bits = jax.lax.bitwise_or(
+        jax.lax.shift_right_logical(bits, np.uint32(9)),
+        np.array(1.0, np.float32).view(np.uint32))
+    floats = (jax.lax.bitcast_convert_type(float_bits, jnp.float32)
+              - np.float32(1.0))
+    tiny = np.float32(np.finfo(np.float32).tiny)
+    u = jax.lax.max(tiny, floats * (np.float32(1.0) - tiny) + tiny)
+    return -jnp.log(-jnp.log(u)).reshape(rows, n)
+
+
 def _categorical_head(key_data, lg, cap: int, take: int):
     """The first ``take`` entries of ``jax.random.categorical(key, lg,
     shape=(cap,))`` WITHOUT materializing the (cap, bs) gumbel tensor.
@@ -83,11 +97,9 @@ def _categorical_head(key_data, lg, cap: int, take: int):
     which pairs counter p with counter p + cap*bs/2 and keeps lane 1 for
     flat positions below the midpoint — so rows [0, take) (flat positions
     [0, take*bs), all below the midpoint when take <= cap//2) are
-    reproducible bit for bit from exactly those counter pairs.  The float
-    conversion replays ``jax.random._uniform``'s mantissa trick and
-    ``gumbel``'s double-log verbatim.  This is what makes the convention
-    affordable at streaming scale: a cell that uses a_c of its cap
-    candidates only ever *computes* max(a_c) rows
+    reproducible bit for bit from exactly those counter pairs.  This is
+    what makes the convention affordable at streaming scale: a cell that
+    uses a_c of its cap candidates only ever *computes* max(a_c) rows
     (:func:`repro.core.streaming.dis_plan_streamed_batched`).
     """
     bs = lg.shape[-1]
@@ -95,15 +107,57 @@ def _categorical_head(key_data, lg, cap: int, take: int):
     x1 = jax.lax.iota(jnp.uint32, take * bs)
     x2 = x1 + jnp.uint32(half)
     bits, _ = _threefry2x32_p.bind(key_data[0], key_data[1], x1, x2)
-    float_bits = jax.lax.bitwise_or(
-        jax.lax.shift_right_logical(bits, np.uint32(9)),
-        np.array(1.0, np.float32).view(np.uint32))
-    floats = (jax.lax.bitcast_convert_type(float_bits, jnp.float32)
-              - np.float32(1.0))
-    tiny = np.float32(np.finfo(np.float32).tiny)
-    u = jax.lax.max(tiny, floats * (np.float32(1.0) - tiny) + tiny)
-    g = -jnp.log(-jnp.log(u)).reshape(take, bs)
-    return jnp.argmax(g + lg[None, :], axis=-1)
+    return jnp.argmax(_gumbel_from_bits(bits, take, bs) + lg[None, :], axis=-1)
+
+
+#: Per-party gumbel bytes above which :func:`_categorical_rows` draws the
+#: round-2 candidates in row chunks (the paper's YearPredictionMSD at
+#: m=2048 would otherwise hold 4.2 GB of noise per party).
+GUMBEL_CHUNK_BYTES = 1 << 28
+
+
+def _threefry_key_data(key):
+    """The raw (2,) uint32 threefry key behind ``key``, or None when the
+    key is of another PRNG implementation."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        if "threefry" not in str(jax.random.key_impl(key)).lower():
+            return None
+        return jax.random.key_data(key)
+    if getattr(jax.config, "jax_default_prng_impl",
+               "threefry2x32") != "threefry2x32":
+        return None
+    return key
+
+
+def _categorical_rows(key, lg, cap: int):
+    """``jax.random.categorical(key, lg, shape=(cap,))``, bit for bit, with
+    the (cap, n) gumbel tensor drawn in row chunks of at most
+    :data:`GUMBEL_CHUNK_BYTES` instead of whole.
+
+    In the partitionable threefry layout (JAX's default) the uniform bits
+    at flat position p of the draw are ``threefry_2x32(key, (0, p))``
+    xor-folded, a function of p alone; so rows [r0, r1) come from counters
+    [r0*n, r1*n), and each row's argmax is unchanged.  Any other layout,
+    dtype or key implementation takes the whole draw.
+    """
+    n = lg.shape[-1]
+    rows = max(1, GUMBEL_CHUNK_BYTES // (4 * n))
+    nchunks = -(-cap // rows)
+    kd = _threefry_key_data(key)
+    if (rows >= cap or kd is None or _threefry2x32_p is None
+            or _float_dtype() != jnp.float32
+            or not getattr(jax.config, "jax_threefry_partitionable", False)
+            or nchunks * rows * n >= 2 ** 32):
+        return jax.random.categorical(key, lg, shape=(cap,))
+
+    def chunk(c):
+        lo = jax.lax.iota(jnp.uint32, rows * n) + c * np.uint32(rows * n)
+        b1, b2 = _threefry2x32_p.bind(kd[0], kd[1], jnp.zeros_like(lo), lo)
+        return jnp.argmax(_gumbel_from_bits(b1 ^ b2, rows, n) + lg[None, :],
+                          axis=-1)
+
+    draws = jax.lax.map(chunk, jnp.arange(nchunks, dtype=jnp.uint32))
+    return draws.reshape(-1)[:cap]
 
 
 def _head_draws_ok(subs, cap: int, bs: int, take: int) -> bool:
@@ -118,10 +172,7 @@ def _head_draws_ok(subs, cap: int, bs: int, take: int) -> bool:
         return False
     if getattr(jax.config, "jax_threefry_partitionable", False):
         return False
-    if jnp.issubdtype(subs.dtype, jax.dtypes.prng_key):
-        return "threefry" in str(jax.random.key_impl(subs)).lower()
-    return getattr(jax.config, "jax_default_prng_impl",
-                   "threefry2x32") == "threefry2x32"
+    return _threefry_key_data(subs) is not None
 
 
 class DisPlan(NamedTuple):
@@ -193,7 +244,7 @@ def dis_plan_full(
     # because draws are iid.
     logits = jnp.log(jnp.maximum(scores, 1e-30))               # (T, n)
     cand = jax.vmap(
-        lambda k, lg: jax.random.categorical(k, lg, shape=(cap,))
+        lambda k, lg: _categorical_rows(k, lg, cap)
     )(subs[1:], logits)                                        # (T, cap)
     take = jnp.arange(cap)[None, :] < a[:, None]               # (T, cap) bool
     # stable selection of exactly m entries (sum(a) = m by construction)

@@ -15,6 +15,15 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
+# The TPU's DEFAULT precision for an f32 dot is one bf16 pass.  Over a short
+# contraction (a quadratic form, a k-means cross term) its 2^-9 rounding
+# survives into the result — measured on a v5e at the paper's
+# YearPredictionMSD size: 2.3e-2 per-row leverage error, 2.1e-3 in k-means
+# distances — so those dots run at HIGHEST.  A Gram's contraction over all
+# n rows averages the rounding out (5.8e-5 per-row score error at DEFAULT,
+# inside the float32 reference tolerance), so the Grams keep DEFAULT.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 # --------------------------------------------------------------------------
 # Algorithm 2: VRLR leverage scores
@@ -27,19 +36,16 @@ def leverage_scores(Xj: jax.Array, rcond: float = 1e-6, use_kernel: bool = True)
     Computed Gram-side: lev_i = x_i^T (X^T X)^+ x_i, which equals the QR-row
     norm but costs O(n d^2 + d^3) instead of an n x d QR, and whose O(n d^2)
     inner loop is the Pallas ``leverage`` kernel (row-wise quadratic form).
-    Handles rank deficiency via eigen-pseudo-inverse.
+    Handles rank deficiency via the equilibrated eigen-pseudo-inverse of
+    :func:`batched_gram_pinv`.
     """
     Xj = jnp.asarray(Xj)
-    n, dj = Xj.shape
-    G = Xj.T @ Xj                                   # (d_j, d_j)
-    evals, evecs = jnp.linalg.eigh(G)
-    cutoff = rcond * jnp.maximum(evals.max(), 0.0)
-    inv = jnp.where(evals > cutoff, 1.0 / jnp.maximum(evals, 1e-30), 0.0)
-    M = (evecs * inv[None, :]) @ evecs.T            # pseudo-inverse of Gram
+    G = Xj.T @ Xj                                             # (d_j, d_j)
+    M = batched_gram_pinv(G[None], rcond)[0]                  # pinv of Gram
     if use_kernel:
         lev = kops.leverage(Xj, M)                  # row-wise x_i^T M x_i
     else:
-        lev = jnp.einsum("nd,de,ne->n", Xj, M, Xj)
+        lev = jnp.einsum("nd,de,ne->n", Xj, M, Xj, precision=HIGHEST)
     # numerical clamp: true leverage lies in [0, 1]
     return jnp.clip(lev, 0.0, 1.0)
 
@@ -106,13 +112,26 @@ def batched_gram_pinv(G: jax.Array, rcond: float = 1e-6,
     widths): a party whose RETAINED rank falls short — a constant or
     duplicated feature slice — reports +inf.  The pinv itself is
     bit-identical either way.
+
+    The Gram is Jacobi-equilibrated first (G -> D G D, D = diag(G)^-1/2,
+    and M = D pinv(D G D) D).  Leverage is invariant to column scaling, but
+    the rcond cutoff is not: an un-centered label column (YearPredictionMSD's
+    years, ~2000) puts the top eigenvalue ~10^7 above the feature spectrum,
+    so an unscaled cutoff drops real feature directions, and which ones
+    flips with float32 rounding.  The cutoff and the condition numbers are
+    therefore those of the equilibrated Gram; an all-zero column scales by
+    0 and stays a zero eigenvalue.
     """
-    evals, evecs = jnp.linalg.eigh(G)
+    dg = jnp.diagonal(G, axis1=1, axis2=2)                 # (T, s)
+    scale = jnp.where(dg > 0, jax.lax.rsqrt(jnp.where(dg > 0, dg, 1.0)), 0.0)
+    Gs = G * scale[:, :, None] * scale[:, None, :]
+    evals, evecs = jnp.linalg.eigh(Gs)
     top = jnp.maximum(evals.max(axis=1), 0.0)              # (T,)
     cutoff = rcond * top
     keep = evals > cutoff[:, None]
     inv = jnp.where(keep, 1.0 / jnp.maximum(evals, 1e-30), 0.0)
-    M = jnp.einsum("tsu,tu,tru->tsr", evecs, inv, evecs)
+    M = jnp.einsum("tsu,tu,tru->tsr", evecs, inv, evecs, precision=HIGHEST)
+    M = M * scale[:, :, None] * scale[:, None, :]
     if not return_cond:
         return M
     small = jnp.min(jnp.where(keep, evals, jnp.inf), axis=1)
@@ -145,7 +164,7 @@ def vrlr_scores_stacked(
     if use_kernel:
         lev = kops.leverage(f, M)                          # (T, n), one dispatch
     else:
-        lev = jnp.einsum("tns,tsr,tnr->tn", f, M, f)
+        lev = jnp.einsum("tns,tsr,tnr->tn", f, M, f, precision=HIGHEST)
     return jnp.clip(lev, 0.0, 1.0) + 1.0 / n
 
 
@@ -162,7 +181,7 @@ def kmeans_assignment(
         return kops.kmeans_assign(Xj, centers)
     d2 = (
         jnp.sum(Xj * Xj, axis=1, keepdims=True)
-        - 2.0 * Xj @ centers.T
+        - 2.0 * jnp.matmul(Xj, centers.T, precision=HIGHEST)
         + jnp.sum(centers * centers, axis=1)[None, :]
     )
     d2 = jnp.maximum(d2, 0.0)

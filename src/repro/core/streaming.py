@@ -73,7 +73,12 @@ from repro.core.dis import (
     _key_chain,
 )
 from repro.core.faults import StreamCheckpoint
-from repro.core.sensitivity import batched_gram_pinv, kmeans_update, norm_scores
+from repro.core.sensitivity import (
+    HIGHEST,
+    batched_gram_pinv,
+    kmeans_update,
+    norm_scores,
+)
 from repro.core.vfl import VFLDataset
 from repro.core.vkmc import kmeans
 from repro.kernels import ops as kops
@@ -253,7 +258,7 @@ def _vrlr_score_body(blk, M, nvalid, n, use_kernel: bool):
     if use_kernel:
         lev = kops.leverage(f, M)
     else:
-        lev = jnp.einsum("tns,tsr,tnr->tn", f, M, f)
+        lev = jnp.einsum("tns,tsr,tnr->tn", f, M, f, precision=HIGHEST)
     sc = jnp.clip(lev, 0.0, 1.0) + 1.0 / n
     ok = jnp.arange(f.shape[1]) < nvalid
     return jnp.where(ok[None, :], sc, 0.0)
@@ -992,8 +997,8 @@ def vrlr_block_masses_sharded(
         f = blk.astype(jnp.float32)
         Gm = jax.lax.psum(jnp.einsum("tns,tnu->tsu", f, f), axis)
         M = batched_gram_pinv(Gm, rcond)
-        sc = jnp.clip(jnp.einsum("tns,tsr,tnr->tn", f, M, f), 0.0, 1.0) \
-            + 1.0 / n
+        lev = jnp.einsum("tns,tsr,tnr->tn", f, M, f, precision=HIGHEST)
+        sc = jnp.clip(lev, 0.0, 1.0) + 1.0 / n
         masses_loc = sc.reshape(T, nb_local, bs).sum(axis=2)
         i = jax.lax.axis_index(axis)
         full = jnp.zeros((T, nb), masses_loc.dtype)

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.comm import CommLedger, null_ledger
+from repro.core.sensitivity import HIGHEST
 from repro.kernels import ops as kops
 
 
@@ -33,7 +34,7 @@ from repro.kernels import ops as kops
 # --------------------------------------------------------------------------
 
 def sq_loss(X: jax.Array, y: jax.Array, theta: jax.Array, w: Optional[jax.Array] = None) -> jax.Array:
-    r = X @ theta - y
+    r = jnp.matmul(X, theta, precision=HIGHEST) - y   # contraction over d
     if w is None:
         return jnp.sum(r * r)
     return jnp.sum(w * r * r)

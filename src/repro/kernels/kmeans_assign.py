@@ -13,7 +13,9 @@ TPU-native design (vs. the usual CUDA one-thread-per-point port):
     k <= O(1e3)), so HBM traffic is exactly one read of X — the kernel is
     memory-bound at roofline, arithmetic intensity ~ k MAC/byte;
   * min + argmin are computed in-register on the (bn, k_pad) distance tile;
-    padded center columns are masked to +inf.
+    padded center columns are masked to +inf;
+  * both outputs leave as lane-dense (1, n_pad) rows, which Mosaic and XLA
+    tile alike, also with a batch axis folded into the grid.
 """
 
 from __future__ import annotations
@@ -32,22 +34,23 @@ def _kernel(x_ref, c_ref, cn_ref, assign_ref, d2_ref, *, k: int):
     x_ref:  (bn, d_pad) points tile            (VMEM)
     c_ref:  (k_pad, d_pad) all centers         (VMEM, same block every step)
     cn_ref: (1, k_pad) precomputed ||c||^2     (VMEM)
-    assign_ref: (bn,) int32 out
-    d2_ref: (bn,) float32 out
+    assign_ref: (1, bn) int32 out              (lane-dense row)
+    d2_ref: (1, bn) float32 out
     """
     x = x_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)                 # (bn, 1)
     # MXU: (bn, d) @ (d, k_pad)
     xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )                                                          # (bn, k_pad)
     d2 = x2 + cn_ref[...] - 2.0 * xc
     k_pad = d2.shape[1]
     col = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
     d2 = jnp.where(col < k, d2, jnp.inf)                       # mask padding
-    assign_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    d2_ref[...] = jnp.maximum(jnp.min(d2, axis=1), 0.0)
+    assign_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)[None, :]
+    d2_ref[...] = jnp.maximum(jnp.min(d2, axis=1), 0.0)[None, :]
 
 
 def _round_up(v: int, m: int) -> int:
@@ -78,7 +81,7 @@ def kmeans_assign(
     # MXU/VPU alignment: lanes = 128, sublanes = 8.
     d_pad = _round_up(max(d, 1), 128)
     k_pad = _round_up(max(k, 1), 128)
-    bn = min(block_n, _round_up(n, 8))
+    bn = min(block_n, _round_up(n, 128))
     n_pad = _round_up(n, bn)
 
     Xp = jnp.zeros((n_pad, d_pad), X.dtype).at[:n, :d].set(X)
@@ -95,13 +98,13 @@ def kmeans_assign(
             pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         ],
         interpret=interpret,
     )(Xp, Cp, cn)
-    return assign[:n], d2[:n]
+    return assign[0, :n], d2[0, :n]

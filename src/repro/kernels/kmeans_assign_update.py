@@ -6,14 +6,14 @@ read) and two ``segment_sum`` scatters — the coordinate-sum scatter
 streams X again (a second X-sized read, plus its (n, d) weighted temp),
 the weight-sum scatter streams the (n,) weights.  This kernel collapses
 all of it to exactly ONE pass over X: in the same VMEM residency that
-computes each (bn, d) tile's distances it also accumulates, into VMEM
-scratch carried across the sequential grid,
+computes each (bn, d) tile's distances it also accumulates, into output
+blocks that stay resident across the sequential grid,
 
   * ``csum``  (k, d) — per-cluster weighted coordinate sums  sum_i w_i x_i,
   * ``wsum``  (k,)   — per-cluster weight mass               sum_i w_i,
   * ``ccost`` (k,)   — per-cluster weighted cost             sum_i w_i d2_i,
 
-and flushes the accumulators to the outputs on the last grid step.  With
+(the last two as the rows of one lane-dense (2, k_pad) block).  With
 unit weights ``wsum``/``ccost`` are the cluster sizes and costs Algorithm 3
 (VKMC sensitivities) needs — so the scoring pass gets them for free from
 the assignment read.
@@ -27,8 +27,10 @@ n-sized weight scatter disappears entirely).
 Leading batch dimensions (stacked parties, multi-seed grids) fold into the
 grid through jax.vmap's native pallas_call batching rule — the batch
 becomes a new leading grid axis; unbatched operands are NOT broadcast, and
-the scratch accumulators re-initialise per batch step because the i == 0 /
-i == nb-1 conditions are evaluated on the original (remapped) grid axis.
+the accumulators re-initialise per batch step because the i == 0 condition
+is evaluated on the original (remapped) grid axis.  Every output block has
+its last two dims either (8, 128)-aligned or equal to the array's, so the
+batched form lowers for Mosaic too.
 """
 
 from __future__ import annotations
@@ -39,35 +41,28 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(
     x_ref, c_ref, cn_ref, w_ref,
-    assign_ref, d2_ref, csum_ref, wsum_ref, ccost_ref,
-    acc_ref, stat_ref,
-    *, k: int, nb: int,
+    assign_ref, d2_ref, csum_ref, stat_ref,
+    *, k: int,
 ):
-    """One grid step: assign a (bn, d_pad) tile and fold it into the scratch
-    accumulators; flush scratch -> outputs on the last step.
+    """One grid step: assign a (bn, d_pad) tile and fold it into the
+    resident accumulator outputs.
 
     x_ref:   (bn, d_pad) points tile             (VMEM)
     c_ref:   (k_pad, d_pad) all centers          (VMEM, same block every step)
     cn_ref:  (1, k_pad) precomputed ||c||^2      (VMEM)
     w_ref:   (bn, 1) per-point weights           (VMEM; 0 on padded rows)
-    assign_ref: (bn,) int32 out
-    d2_ref:  (bn,) float32 out
-    csum_ref:  (k_pad, d_pad) out                (written on last step)
-    wsum_ref:  (k_pad,) out                      (written on last step)
-    ccost_ref: (k_pad,) out                      (written on last step)
-    acc_ref:  (k_pad, d_pad) VMEM scratch — csum accumulator
-    stat_ref: (2, k_pad) VMEM scratch — [wsum; ccost] accumulators
+    assign_ref: (1, bn) int32 out                (lane-dense row)
+    d2_ref:  (1, bn) float32 out
+    csum_ref: (k_pad, d_pad) out — csum accumulator, same block every step
+    stat_ref: (2, k_pad) out — [wsum; ccost] accumulators, same block
     """
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        csum_ref[...] = jnp.zeros_like(csum_ref)
         stat_ref[...] = jnp.zeros_like(stat_ref)
 
     x = x_ref[...].astype(jnp.float32)                         # (bn, d_pad)
@@ -76,30 +71,26 @@ def _kernel(
     x2 = jnp.sum(x * x, axis=1, keepdims=True)                 # (bn, 1)
     # MXU: (bn, d) @ (d, k_pad) — same distance tile as kmeans_assign
     xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )                                                          # (bn, k_pad)
     d2 = x2 + cn_ref[...] - 2.0 * xc
     col = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
     d2 = jnp.where(col < k, d2, jnp.inf)                       # mask padding
     assign = jnp.argmin(d2, axis=1).astype(jnp.int32)
     d2min = jnp.maximum(jnp.min(d2, axis=1), 0.0)
-    assign_ref[...] = assign
-    d2_ref[...] = d2min
+    assign_ref[...] = assign[None, :]
+    d2_ref[...] = d2min[None, :]
 
     # weighted one-hot fold: wh[i, l] = w_i * [assign_i == l]
     wh = jnp.where(col == assign[:, None], w, 0.0)             # (bn, k_pad)
     # MXU: (k_pad, bn) @ (bn, d_pad) — per-cluster coordinate sums
-    acc_ref[...] += jax.lax.dot_general(
-        wh, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    csum_ref[...] += jax.lax.dot_general(
+        wh, x, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
-    stat_ref[0, :] += jnp.sum(wh, axis=0)
-    stat_ref[1, :] += jnp.sum(wh * d2min[:, None], axis=0)
-
-    @pl.when(i == nb - 1)
-    def _flush():
-        csum_ref[...] = acc_ref[...]
-        wsum_ref[...] = stat_ref[0, :]
-        ccost_ref[...] = stat_ref[1, :]
+    stat_ref[0:1, :] += jnp.sum(wh, axis=0, keepdims=True)
+    stat_ref[1:2, :] += jnp.sum(wh * d2min[:, None], axis=0, keepdims=True)
 
 
 def _round_up(v: int, m: int) -> int:
@@ -144,7 +135,7 @@ def kmeans_assign_update(
     k = C.shape[0]
     d_pad = _round_up(max(d, 1), 128)
     k_pad = _round_up(max(k, 1), 128)
-    bn = min(block_n, _round_up(n, 8))
+    bn = min(block_n, _round_up(n, 128))
     n_pad = _round_up(n, bn)
     nb = n_pad // bn
 
@@ -155,8 +146,8 @@ def kmeans_assign_update(
     wn = jnp.ones((n,), jnp.float32) if w is None else w.astype(jnp.float32)
     wp = jnp.zeros((n_pad, 1), jnp.float32).at[:n, 0].set(wn)
 
-    assign, d2, csum, wsum, ccost = pl.pallas_call(
-        functools.partial(_kernel, k=k, nb=nb),
+    assign, d2, csum, stat = pl.pallas_call(
+        functools.partial(_kernel, k=k),
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((bn, d_pad), lambda i: (i, 0)),
@@ -165,23 +156,17 @@ def kmeans_assign_update(
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((k_pad, d_pad), lambda i: (0, 0)),
-            pl.BlockSpec((k_pad,), lambda i: (0,)),
-            pl.BlockSpec((k_pad,), lambda i: (0,)),
+            pl.BlockSpec((2, k_pad), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
             jax.ShapeDtypeStruct((k_pad, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((k_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((k_pad,), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((k_pad, d_pad), jnp.float32),
-            pltpu.VMEM((2, k_pad), jnp.float32),
+            jax.ShapeDtypeStruct((2, k_pad), jnp.float32),
         ],
         interpret=interpret,
     )(Xp, Cp, cn, wp)
-    return assign[:n], d2[:n], csum[:k, :d], wsum[:k], ccost[:k]
+    return assign[0, :n], d2[0, :n], csum[:k, :d], stat[0, :k], stat[1, :k]

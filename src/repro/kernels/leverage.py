@@ -4,7 +4,9 @@ This is the O(n*d^2) hot loop of Algorithm 2 (VRLR leverage scores): after a
 party inverts its (d_j x d_j) local Gram matrix once, every row's leverage
 score is a quadratic form against that inverse.  On TPU the (bn, d) @ (d, d)
 product runs on the MXU; the Hadamard-and-reduce epilogue runs on the VPU in
-the same VMEM residency, so X is read from HBM exactly once.
+the same VMEM residency, so X is read from HBM exactly once.  The scores
+leave as a lane-dense (1, n_pad) row: a 1-D output is tiled differently by
+Mosaic and XLA, and a batched 1-D block breaks Mosaic's (8, 128) rule.
 """
 
 from __future__ import annotations
@@ -20,9 +22,10 @@ def _kernel(x_ref, m_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)                       # (bn, d_pad)
     m = m_ref[...].astype(jnp.float32)                       # (d_pad, d_pad)
     xm = jax.lax.dot_general(
-        x, m, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x, m, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )                                                        # (bn, d_pad)
-    out_ref[...] = jnp.sum(xm * x, axis=1)
+    out_ref[...] = jnp.sum(xm * x, axis=1)[None, :]         # (1, bn) lane-dense
 
 
 def _round_up(v: int, m: int) -> int:
@@ -50,7 +53,7 @@ def leverage(
         )(X, M)
     n, d = X.shape
     d_pad = _round_up(max(d, 1), 128)
-    bn = min(block_n, _round_up(n, 8))
+    bn = min(block_n, _round_up(n, 128))
     n_pad = _round_up(n, bn)
 
     Xp = jnp.zeros((n_pad, d_pad), X.dtype).at[:n, :d].set(X)
@@ -63,8 +66,8 @@ def leverage(
             pl.BlockSpec((bn, d_pad), lambda i: (i, 0)),
             pl.BlockSpec((d_pad, d_pad), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.float32),
+        out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         interpret=interpret,
     )(Xp, Mp)
-    return out[:n]
+    return out[0, :n]

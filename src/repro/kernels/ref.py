@@ -12,6 +12,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+# f32 semantics for the short contractions on every backend (the TPU's
+# DEFAULT f32 dot is one bf16 pass; see repro.core.sensitivity.HIGHEST)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _batched(fn, *args, axes):
     """vmap ``fn`` over axis 0 of the args whose entry in ``axes`` is 0 —
@@ -32,7 +36,8 @@ def kmeans_assign(X: jax.Array, C: jax.Array) -> Tuple[jax.Array, jax.Array]:
                               0 if C.ndim > 2 else None))
     x2 = jnp.sum(X.astype(jnp.float32) ** 2, axis=1, keepdims=True)        # (n, 1)
     c2 = jnp.sum(C.astype(jnp.float32) ** 2, axis=1)[None, :]              # (1, k)
-    xc = X.astype(jnp.float32) @ C.astype(jnp.float32).T                   # (n, k)
+    xc = jnp.matmul(X.astype(jnp.float32), C.astype(jnp.float32).T,
+                    precision=HIGHEST)                                     # (n, k)
     d2 = jnp.maximum(x2 + c2 - 2.0 * xc, 0.0)
     return jnp.argmin(d2, axis=1).astype(jnp.int32), jnp.min(d2, axis=1)
 
@@ -77,7 +82,7 @@ def leverage(X: jax.Array, M: jax.Array) -> jax.Array:
                               0 if M.ndim > 2 else None))
     Xf = X.astype(jnp.float32)
     Mf = M.astype(jnp.float32)
-    return jnp.einsum("nd,de,ne->n", Xf, Mf, Xf)
+    return jnp.einsum("nd,de,ne->n", Xf, Mf, Xf, precision=HIGHEST)
 
 
 def weighted_gram(X: jax.Array, w: jax.Array) -> jax.Array:

@@ -28,7 +28,8 @@ def _kernel(x_ref, w_ref, out_ref):
     w = w_ref[...].astype(jnp.float32)                     # (bn, 1)
     xw = x * w                                             # VPU broadcast
     out_ref[...] += jax.lax.dot_general(
-        xw, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        xw, x, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )                                                      # MXU (d, d) update
 
 

@@ -67,3 +67,37 @@ def test_uniform_sample_weights():
 def test_dis_rejects_zero_scores():
     with pytest.raises(ValueError):
         dis_sample(jax.random.PRNGKey(0), [jnp.zeros((10,))], 5)
+
+
+@pytest.mark.parametrize("cap,n,rows", [(7, 33, 2), (64, 100, 5), (5, 50, 1)])
+@pytest.mark.parametrize("typed_key", [False, True])
+def test_row_chunked_categorical_is_bit_identical(monkeypatch, cap, n, rows,
+                                                  typed_key):
+    """The round-2 draw in row chunks replays jax.random.categorical draw
+    for draw, including a last chunk that overhangs cap, and under vmap."""
+    from repro.core import dis
+
+    monkeypatch.setattr(dis, "GUMBEL_CHUNK_BYTES", 4 * n * rows)
+    key = jax.random.key(4) if typed_key else jax.random.PRNGKey(3)
+    lg = jax.random.normal(jax.random.PRNGKey(1), (n,))
+    np.testing.assert_array_equal(
+        np.asarray(dis._categorical_rows(key, lg, cap)),
+        np.asarray(jax.random.categorical(key, lg, shape=(cap,))))
+    keys = jax.random.split(key, 3)
+    lgs = jax.random.normal(jax.random.PRNGKey(2), (3, n))
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(lambda k, l: dis._categorical_rows(k, l, cap))(
+            keys, lgs)),
+        np.asarray(jax.vmap(
+            lambda k, l: jax.random.categorical(k, l, shape=(cap,)))(keys, lgs)))
+
+
+def test_dis_plan_full_unchanged_by_row_chunking(monkeypatch):
+    from repro.core import dis
+
+    scores = jnp.stack(_scores(jax.random.PRNGKey(5), 300, 3))
+    whole = dis.dis_plan_full(jax.random.PRNGKey(6), scores, 40)
+    monkeypatch.setattr(dis, "GUMBEL_CHUNK_BYTES", 4 * 300 * 3)
+    chunked = dis.dis_plan_full(jax.random.PRNGKey(6), scores, 40)
+    for a, b in zip(whole, chunked):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

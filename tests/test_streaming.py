@@ -282,7 +282,7 @@ def test_backend_auto_resolution():
     assert resolve_backend("ref") == "ref"
     assert resolve_backend("norm") == "norm"
     resolved = resolve_backend("auto")
-    if jax.default_backend() in ("tpu", "gpu"):
+    if jax.default_backend() == "tpu":
         assert resolved == "pallas"
     else:
         assert resolved == "ref"

@@ -82,6 +82,7 @@ from repro.core.sensitivity import (
 from repro.core.vfl import VFLDataset
 from repro.core.vkmc import kmeans
 from repro.core.wire import WirePayload, get_codec
+from repro.utils import trace
 from repro.utils.registry import Registry
 
 CORESET_TASKS = Registry("coreset_task")
@@ -186,10 +187,12 @@ def vrlr_scores(key, ds: VFLDataset, backend: str = "pallas"):
     ((T, n, s) blocks, labels pre-appended): batched Gram + eigh, then a
     single party-batched ``leverage`` kernel call — no Python party loop.
     """
-    st = ds.stacked(with_labels=True)
-    if backend == "norm":
-        return norm_scores(st.blocks) + 1.0 / ds.n, key
-    return vrlr_scores_stacked(st.blocks, use_kernel=_use_kernel(backend)), key
+    with jax.named_scope("score"):
+        st = ds.stacked(with_labels=True)
+        if backend == "norm":
+            return norm_scores(st.blocks) + 1.0 / ds.n, key
+        scores = vrlr_scores_stacked(st.blocks, use_kernel=_use_kernel(backend))
+    return scores, key
 
 
 @register_task("vkmc", deterministic_scores=False,
@@ -212,17 +215,17 @@ def vkmc_scores(key, ds: VFLDataset, backend: str = "pallas",
         key, sub = jax.random.split(key)
         subs.append(sub)
     key, dis_key = jax.random.split(key)
-    st = ds.stacked()
-    if backend == "norm":
-        return norm_scores(st.blocks) + 1.0 / ds.n, dis_key
-
     use_kernel = _use_kernel(backend)
 
     def party(sub, Xb):
         local_c = kmeans(sub, Xb, k, iters=local_iters, use_kernel=use_kernel)
         return vkmc_local_scores(Xb, local_c, alpha, use_kernel=use_kernel)
 
-    return jax.vmap(party)(jnp.stack(subs), st.blocks), dis_key
+    with jax.named_scope("score"):
+        st = ds.stacked()
+        if backend == "norm":
+            return norm_scores(st.blocks) + 1.0 / ds.n, dis_key
+        return jax.vmap(party)(jnp.stack(subs), st.blocks), dis_key
 
 
 CORESET_TASKS.register("uniform")(
@@ -459,6 +462,26 @@ def _ship_round2(
     return out, retry_units, retry_bits
 
 
+def _require_positive(totals) -> None:
+    """DIS needs a positive total score; the read blocks on the device."""
+    with trace.span("wait", of="totals"):
+        ok = bool(totals.sum() > 0)
+    if not ok:
+        raise ValueError("DIS requires a positive total score")
+
+
+def _bill_dis(T: int, m: int, plan, ledger: Optional[CommLedger],
+              round1_payload: WirePayload) -> CommSchedule:
+    """Record the DIS bill of a realised plan (the transportless path)."""
+    with trace.span("wait", of="counts"):
+        counts = np.asarray(plan.counts)
+    with trace.span("bill"):
+        schedule = CommSchedule.dis(T, m, counts=counts,
+                                    round1_payload=round1_payload)
+        schedule.record(ledger)
+    return schedule
+
+
 def _exec_materialized(
     spec: CoresetTask, ds: VFLDataset, m: int, key, backend: str,
     ledger: Optional[CommLedger], params: dict,
@@ -506,20 +529,21 @@ def _exec_materialized(
                 f"a transport nothing crosses it — the recorded path "
                 f"supports codec='raw_fp32' only"
             )
-        scores, dis_key = spec.score_fn(key, ds, backend=backend, **params)
-        plan = dis_plan_full(dis_key, scores, m)
-        if not bool(plan.totals.sum() > 0):
-            raise ValueError("DIS requires a positive total score")
-        schedule = CommSchedule.dis(ds.T, m, counts=np.asarray(plan.counts),
-                                    round1_payload=r1_payload)
-        schedule.record(ledger)
+        with trace.span("score"):
+            scores, dis_key = spec.score_fn(key, ds, backend=backend, **params)
+        with trace.span("dis"):
+            plan = dis_plan_full(dis_key, scores, m)
+        _require_positive(plan.totals)
+        schedule = _bill_dis(ds.T, m, plan, ledger, r1_payload)
+        with trace.span("health"):
+            health = health_from_masses(np.asarray(scores))
         return Coreset(plan.indices, plan.weights, schedule.total,
-                       comm_bits=schedule.total_bits,
-                       health=health_from_masses(np.asarray(scores)))
+                       comm_bits=schedule.total_bits, health=health)
 
     eff_ds, alive, degraded, units1, bits1 = _faulted_round1(
         spec, ds, transport, ledger, fault_policy, payload=r1_payload)
-    scores, dis_key = spec.score_fn(key, eff_ds, backend=backend, **params)
+    with trace.span("score"):
+        scores, dis_key = spec.score_fn(key, eff_ds, backend=backend, **params)
     # integrity seam: the per-row score table IS this engine's round-1 mass
     # payload — ship it under envelopes, validate what arrived
     delivered, offenders, ship_units, ship_bits = _integrity_round1(
@@ -529,18 +553,20 @@ def _exec_materialized(
         eff_ds, alive, degraded = _quarantine(spec, ds, alive, degraded,
                                               offenders)
         # rescore the survivors; their tables already validated clean
-        scores, dis_key = spec.score_fn(key, eff_ds, backend=backend,
-                                        **params)
+        with trace.span("score"):
+            scores, dis_key = spec.score_fn(key, eff_ds, backend=backend,
+                                            **params)
     elif delivered is not None:
         # what crossed the wire drives the draw: a lossy codec's quantized
         # table on the clean path, or — with verification off — corrupted
         # masses, exactly the undefended blow-up the integrity benchmark
         # measures
         scores = jnp.asarray(delivered)
-    health = health_from_masses(np.asarray(scores))
-    plan = dis_plan_full(dis_key, scores, m)
-    if not bool(plan.totals.sum() > 0):
-        raise ValueError("DIS requires a positive total score")
+    with trace.span("health"):
+        health = health_from_masses(np.asarray(scores))
+    with trace.span("dis"):
+        plan = dis_plan_full(dis_key, scores, m)
+    _require_positive(plan.totals)
     # rounds 2-3 exhaust hard even under degrade: by now the scores exist
     # and dropping a party would orphan its drawn rows (documented)
     up_payloads, up_blobs = _round2_wire(plan, alive, ds.T, codec)
@@ -604,12 +630,9 @@ def _exec_fused(
         fn = jax.jit(_build)
         _JIT_BUILDERS[cache_key] = fn
     plan = fn(key, tuple(ds.parts), ds.y)
-    if not bool(plan.totals.sum() > 0):
-        raise ValueError("DIS requires a positive total score")
-    schedule = CommSchedule.dis(
-        ds.T, m, counts=np.asarray(plan.counts),
-        round1_payload=WirePayload.of((ds.n,), "float32", "raw_fp32"))
-    schedule.record(ledger)
+    _require_positive(plan.totals)
+    schedule = _bill_dis(ds.T, m, plan, ledger,
+                         WirePayload.of((ds.n,), "float32", "raw_fp32"))
     return Coreset(plan.indices, plan.weights, schedule.total,
                    comm_bits=schedule.total_bits)
 
@@ -736,7 +759,8 @@ def _exec_streaming(
                                   prefetch=prefetch, masses=masses,
                                   ckpt=checkpoint, **params)
 
-    scorer = _build_scorer(eff_ds)
+    with trace.span("score"):
+        scorer = _build_scorer(eff_ds)
     ship_units = ship_bits = 0
     if transport is not None:
         # integrity seam: the (T, nb) block-mass table is the streamed
@@ -747,25 +771,25 @@ def _exec_streaming(
         if offenders:
             eff_ds, alive, degraded = _quarantine(spec, ds, alive, degraded,
                                                   offenders)
-            scorer = _build_scorer(eff_ds)  # rescore the survivors
+            with trace.span("score"):
+                scorer = _build_scorer(eff_ds)  # rescore the survivors
         elif delivered is not None:
             # what crossed the wire drives the draw: the lossy codec's
             # quantized table, or — unverified — a corrupted one
             scorer = with_masses(scorer, delivered)
-    health = health_from_masses(np.asarray(scorer.masses),
-                                gram_conds=scorer.gram_conds)
-    if not bool(scorer.masses.sum() > 0):
-        raise ValueError("DIS requires a positive total score")
-    if pipelined:
-        plan = dis_plan_streamed_batched(scorer, m, probe=probe)
-    else:
-        plan = dis_plan_streamed(scorer, m, probe=probe)
+    with trace.span("health"):
+        health = health_from_masses(np.asarray(scorer.masses),
+                                    gram_conds=scorer.gram_conds)
+    _require_positive(scorer.masses)
+    with trace.span("dis"):
+        if pipelined:
+            plan = dis_plan_streamed_batched(scorer, m, probe=probe)
+        else:
+            plan = dis_plan_streamed(scorer, m, probe=probe)
     if checkpoint is not None:
         checkpoint.clear()            # the build completed; state is stale
     if transport is None:
-        schedule = CommSchedule.dis(ds.T, m, counts=np.asarray(plan.counts),
-                                    round1_payload=r1_payload)
-        schedule.record(ledger)
+        schedule = _bill_dis(ds.T, m, plan, ledger, r1_payload)
         return Coreset(plan.indices, plan.weights, schedule.total,
                        comm_bits=schedule.total_bits, health=health)
     up_payloads, up_blobs = _round2_wire(plan, alive, ds.T, codec)
@@ -978,6 +1002,12 @@ class CoresetPipeline:
                 )
         else:
             ep = self.plan(spec)
+        with trace.span("build", build=next(trace.BUILDS), engine=ep.engine):
+            return self._dispatch(ep, key, keys, ledger, probe, transport,
+                                  checkpoint)
+
+    def _dispatch(self, ep: ExecutionPlan, key, keys, ledger, probe,
+                  transport, checkpoint) -> Union[Coreset, BatchedCoresets]:
         cspec = ep.spec
         task = get_task(cspec.task)
 

@@ -226,30 +226,31 @@ def dis_plan_full(
     cap = int(m) if m_cap is None else int(m_cap)
     valid = jnp.arange(cap) < m                                # all True if static
 
-    subs = _key_chain(key, T + 1)
-    G_j = (jnp.sum(scores, axis=1) if totals is None
-           else totals.astype(_float_dtype()))                 # (T,)
-    G = G_j.sum()
-
     # ---- round 1: a ~ Multinomial(m, G_j/G), realised as m iid draws --------
-    draws = jax.random.categorical(
-        subs[0], jnp.log(jnp.maximum(G_j, 1e-30)), shape=(cap,)
-    )
-    a = jnp.zeros((T,), jnp.int32).at[draws].add(valid.astype(jnp.int32))
+    with jax.named_scope("dis_round1"):
+        subs = _key_chain(key, T + 1)
+        G_j = (jnp.sum(scores, axis=1) if totals is None
+               else totals.astype(_float_dtype()))             # (T,)
+        G = G_j.sum()
+        draws = jax.random.categorical(
+            subs[0], jnp.log(jnp.maximum(G_j, 1e-30)), shape=(cap,)
+        )
+        a = jnp.zeros((T,), jnp.int32).at[draws].add(valid.astype(jnp.int32))
 
     # ---- round 2: party-local index sampling, then server union -------------
     # Party j draws a_j iid indices ~ g_i^(j)/G^(j).  To keep everything
     # static-shape we draw `cap` candidates per party and select the first
     # a_j of each via a mask when concatenating — statistically identical
     # because draws are iid.
-    logits = jnp.log(jnp.maximum(scores, 1e-30))               # (T, n)
-    cand = jax.vmap(
-        lambda k, lg: _categorical_rows(k, lg, cap)
-    )(subs[1:], logits)                                        # (T, cap)
-    take = jnp.arange(cap)[None, :] < a[:, None]               # (T, cap) bool
-    # stable selection of exactly m entries (sum(a) = m by construction)
-    order = jnp.argsort(~take.reshape(-1), stable=True)        # taken slots first
-    S = cand.reshape(-1)[order][:cap]                          # (cap,)
+    with jax.named_scope("dis_round2"):
+        logits = jnp.log(jnp.maximum(scores, 1e-30))           # (T, n)
+        cand = jax.vmap(
+            lambda k, lg: _categorical_rows(k, lg, cap)
+        )(subs[1:], logits)                                    # (T, cap)
+        take = jnp.arange(cap)[None, :] < a[:, None]           # (T, cap) bool
+        # stable selection of exactly m entries (sum(a) = m by construction)
+        order = jnp.argsort(~take.reshape(-1), stable=True)    # taken slots first
+        S = cand.reshape(-1)[order][:cap]                      # (cap,)
 
     # ---- round 3: per-sample local scores up, weights at server -------------
     # Sequential per-party accumulation (scan) keeps the float addition order
@@ -257,8 +258,10 @@ def dis_plan_full(
     def add_party(acc, g_row):
         return acc + g_row[S], None
 
-    g_sum_S, _ = jax.lax.scan(add_party, jnp.zeros((cap,), scores.dtype), scores)
-    w = G / (m * jnp.maximum(g_sum_S, 1e-30))
+    with jax.named_scope("dis_round3"):
+        g_sum_S, _ = jax.lax.scan(add_party, jnp.zeros((cap,), scores.dtype),
+                                  scores)
+        w = G / (m * jnp.maximum(g_sum_S, 1e-30))
     if not static_m:
         S = jnp.where(valid, S, 0)
         w = jnp.where(valid, w, 0.0)

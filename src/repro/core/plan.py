@@ -56,6 +56,7 @@ from repro.core.wire import (
     predict_dis_bits,
     predict_uniform_bits,
 )
+from repro.utils import trace
 
 SCORE_BACKENDS = ("pallas", "ref", "norm")
 
@@ -662,6 +663,11 @@ def compile_plan(spec: CoresetSpec, ds: VFLDataset) -> ExecutionPlan:
     default).  Raises the task's label requirement eagerly so a bad spec
     fails before any engine runs.
     """
+    with trace.span("plan"):
+        return _compile_plan(spec, ds)
+
+
+def _compile_plan(spec: CoresetSpec, ds: VFLDataset) -> ExecutionPlan:
     import jax
 
     from repro.core.api import get_task, resolve_backend

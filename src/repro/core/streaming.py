@@ -83,6 +83,7 @@ from repro.core.vfl import VFLDataset
 from repro.core.vkmc import kmeans
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.utils import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -325,11 +326,12 @@ def _mass_table(ds, block_size, score_block, probe, ckpt=None):
     nb, _ = ds.block_geometry(block_size)
     start, saved = _ckpt_load(ckpt, "mass")
     masses = list(saved) if saved is not None else []
-    for b in range(start, nb):
-        masses.append(jnp.sum(score_block(b), axis=1))
-        _ckpt_save(ckpt, "mass", b + 1, tuple(masses))
-        probe()
-    return jnp.stack(masses, axis=1)                       # (T, nb)
+    with trace.span("score.mass"):
+        for b in range(start, nb):
+            masses.append(jnp.sum(score_block(b), axis=1))
+            _ckpt_save(ckpt, "mass", b + 1, tuple(masses))
+            probe()
+        return jnp.stack(masses, axis=1)                   # (T, nb)
 
 
 def _chunked_mass_table(ds, block_size, chunk_blocks, prefetch, probe,
@@ -341,14 +343,15 @@ def _chunked_mass_table(ds, block_size, chunk_blocks, prefetch, probe,
     nb, _ = ds.block_geometry(block_size)
     start, saved = _ckpt_load(ckpt, "mass")
     cols = list(saved) if saved is not None else []
-    for b0, chunk, nvalids in ds.blocks_prefetched(
-            block_size, with_labels, chunk_blocks, prefetch,
-            start_chunk=start):
-        cols.append(mass_chunk(chunk, jnp.asarray(nvalids)))   # (T, C)
-        del chunk            # drop the slot before the next one is staged
-        _ckpt_save(ckpt, "mass", b0 // chunk_blocks + 1, tuple(cols))
-        probe()
-    return jnp.concatenate(cols, axis=1)[:, :nb]
+    with trace.span("score.mass"):
+        for b0, chunk, nvalids in ds.blocks_prefetched(
+                block_size, with_labels, chunk_blocks, prefetch,
+                start_chunk=start):
+            cols.append(mass_chunk(chunk, jnp.asarray(nvalids)))   # (T, C)
+            del chunk        # drop the slot before the next one is staged
+            _ckpt_save(ckpt, "mass", b0 // chunk_blocks + 1, tuple(cols))
+            probe()
+        return jnp.concatenate(cols, axis=1)[:, :nb]
 
 
 @register_stream_scorer("vrlr")
@@ -405,23 +408,25 @@ def vrlr_stream_scorer(
         start, saved = _ckpt_load(ckpt, "gram")
         G = saved if saved is not None else jnp.zeros((ds.T, s, s),
                                                       jnp.float32)
-        if pipelined:
-            for b0, chunk, nvalids in ds.blocks_prefetched(
-                    block_size, True, C, prefetch, start_chunk=start):
-                G = _gram_chunk(G, chunk, jnp.asarray(nvalids),
-                                use_kernel=use_kernel)
-                del chunk    # drop the slot before the next one is staged
-                _ckpt_save(ckpt, "gram", b0 // C + 1, G)
-                probe()
-        else:
-            for b, blk, nvalid in ds.blocks(block_size, with_labels=True):
-                if b < start:
-                    continue
-                G = _gram_step(G, blk, nvalid, use_kernel=use_kernel)
-                _ckpt_save(ckpt, "gram", b + 1, G)
-                probe()
-        M, gram_conds = batched_gram_pinv(G, rcond, return_cond=True,
-                                          expected_rank=widths)
+        with trace.span("score.gram"):
+            if pipelined:
+                for b0, chunk, nvalids in ds.blocks_prefetched(
+                        block_size, True, C, prefetch, start_chunk=start):
+                    G = _gram_chunk(G, chunk, jnp.asarray(nvalids),
+                                    use_kernel=use_kernel)
+                    del chunk    # drop the slot before the next is staged
+                    _ckpt_save(ckpt, "gram", b0 // C + 1, G)
+                    probe()
+            else:
+                for b, blk, nvalid in ds.blocks(block_size, with_labels=True):
+                    if b < start:
+                        continue
+                    G = _gram_step(G, blk, nvalid, use_kernel=use_kernel)
+                    _ckpt_save(ckpt, "gram", b + 1, G)
+                    probe()
+        with trace.span("score.pinv"):
+            M, gram_conds = batched_gram_pinv(G, rcond, return_cond=True,
+                                              expected_rank=widths)
 
         def score_block(b: int) -> jax.Array:
             blk, nvalid = ds.block(b, block_size, with_labels=True)
@@ -728,7 +733,9 @@ def dis_plan_streamed(
     draws = jax.random.categorical(
         subs[0], jnp.log(jnp.maximum(masses.reshape(-1), 1e-30)), shape=(cap,)
     )
-    a_cells = np.bincount(np.asarray(draws), minlength=ncells)
+    with trace.span("wait", of="draws"):
+        draws = np.asarray(draws)
+    a_cells = np.bincount(draws, minlength=ncells)
 
     # ---- rounds 2+3: recompute each touched block ONCE, draw its cells' rows
     # and gather their combined scores, then DISCARD the block's scores — at
@@ -773,6 +780,7 @@ def dis_plan_streamed(
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "take", "head"))
+@jax.named_scope("dis_redraw")
 def _group_candidates(sc_g, subs, cells, gidx, jidx, bids, n,
                       *, cap: int, take: int, head: bool):
     """Rounds 2+3 for every occupied cell of one touched-block group in ONE
@@ -843,7 +851,9 @@ def dis_plan_streamed_batched(
         draws = jax.random.categorical(
             subs[0], jnp.log(jnp.maximum(masses.reshape(-1), 1e-30)),
             shape=(cap,))
-        a_cells = np.bincount(np.asarray(draws), minlength=ncells)
+        with trace.span("wait", of="draws"):
+            draws = np.asarray(draws)
+        a_cells = np.bincount(draws, minlength=ncells)
     else:
         a_cells = np.zeros((ncells,), np.int64)
 
@@ -898,8 +908,9 @@ def dis_plan_streamed_batched(
             sc_g, subs, jnp.asarray(cells), jnp.asarray(gidx),
             jnp.asarray(jidx), jnp.asarray(bids), n,
             cap=cap, take=take_eff, head=head)
-        rows = np.asarray(rows)
-        gath = np.asarray(gath)
+        with trace.span("wait", of="rows"):
+            rows = np.asarray(rows)
+            gath = np.asarray(gath)
         for i, c in enumerate(cells[:nc]):
             a_c = int(a_cells[c])
             per_cell[c] = (rows[i, :a_c], gath[i, :a_c])

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import trace
+
 
 class StackedParts(NamedTuple):
     """Padded party-major view of a :class:`VFLDataset`.
@@ -316,10 +318,13 @@ class VFLDataset:
         dt = self._staging_dtype(with_labels)
 
         def stage(c: int):
-            buf = np.empty((chunk_blocks, self.T, bs, s), dt)
-            nvalids = self._fill_superchunk(buf, c * chunk_blocks, block_size,
-                                            with_labels, widths, bs, nb)
-            return jax.device_put(buf), nvalids          # async: returns now
+            with trace.span("stage"):
+                buf = np.empty((chunk_blocks, self.T, bs, s), dt)
+                nvalids = self._fill_superchunk(buf, c * chunk_blocks,
+                                                block_size, with_labels,
+                                                widths, bs, nb)
+                trace.add(bytes=buf.nbytes)
+                return jax.device_put(buf), nvalids      # async: returns now
 
         if not prefetch:
             for c in range(start_chunk, nchunks):
@@ -351,13 +356,15 @@ class VFLDataset:
         for b in ids:
             if not 0 <= b < nb:
                 raise IndexError(f"block {b} out of range [0, {nb})")
-        out = np.empty((len(ids), self.T, bs, s),
-                       self._staging_dtype(with_labels))
-        nvalids = np.zeros((len(ids),), np.int64)
-        for i, b in enumerate(ids):
-            nvalids[i:i + 1] = self._fill_superchunk(
-                out[i:i + 1], b, block_size, with_labels, widths, bs, nb)
-        return jax.device_put(out), nvalids
+        with trace.span("stage"):
+            out = np.empty((len(ids), self.T, bs, s),
+                           self._staging_dtype(with_labels))
+            nvalids = np.zeros((len(ids),), np.int64)
+            for i, b in enumerate(ids):
+                nvalids[i:i + 1] = self._fill_superchunk(
+                    out[i:i + 1], b, block_size, with_labels, widths, bs, nb)
+            trace.add(bytes=out.nbytes)
+            return jax.device_put(out), nvalids
 
     def rows(self, idx: jnp.ndarray) -> "VFLDataset":
         y = None if self.y is None else self.y[idx]

@@ -106,5 +106,6 @@ def kmeans_assign(
             jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign",
     )(Xp, Cp, cn)
     return assign[0, :n], d2[0, :n]

@@ -168,5 +168,6 @@ def kmeans_assign_update(
             jax.ShapeDtypeStruct((2, k_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign_update",
     )(Xp, Cp, cn, wp)
     return assign[0, :n], d2[0, :n], csum[:k, :d], stat[0, :k], stat[1, :k]

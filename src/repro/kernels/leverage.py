@@ -69,5 +69,6 @@ def leverage(
         out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         interpret=interpret,
+        name="leverage",
     )(Xp, Mp)
     return out[0, :n]

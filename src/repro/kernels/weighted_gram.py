@@ -75,5 +75,6 @@ def weighted_gram(
         out_specs=pl.BlockSpec((d_pad, d_pad), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad, d_pad), jnp.float32),
         interpret=interpret,
+        name="weighted_gram",
     )(Xp, wp)
     return out[:d, :d]

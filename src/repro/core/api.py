@@ -45,7 +45,13 @@ import numpy as np
 
 from repro.core.comm import CommLedger, CommSchedule
 from repro.core.coreset import Coreset
-from repro.core.dis import _float_dtype, dis_plan_full, split_uploads, uniform_plan
+from repro.core.dis import (
+    _float_dtype,
+    dis_plan_compiled,
+    dis_plan_full,
+    split_uploads,
+    uniform_plan,
+)
 from repro.core.faults import (
     DeadlineExceeded,
     DegradedBuild,
@@ -488,8 +494,10 @@ def _exec_materialized(
     transport: Optional[Transport] = None, fault_policy: str = "fail",
     codec: str = "raw_fp32",
 ) -> Coreset:
-    """The eager sequential engine — the fidelity reference against the
-    seed's builders (scores computed eagerly, DIS on the full matrix).
+    """The sequential engine — the fidelity reference against the seed's
+    builders: scores computed eagerly, then DIS on the full matrix as one
+    compiled dispatch (:func:`dis_plan_compiled`, bit-identical to an eager
+    :func:`dis_plan_full`).
 
     With a ``transport`` the DIS rounds are DELIVERED instead of recorded:
     round 1 before scoring (where ``degrade`` can still drop a party —
@@ -532,7 +540,7 @@ def _exec_materialized(
         with trace.span("score"):
             scores, dis_key = spec.score_fn(key, ds, backend=backend, **params)
         with trace.span("dis"):
-            plan = dis_plan_full(dis_key, scores, m)
+            plan = dis_plan_compiled(dis_key, scores, m, core=dis_plan_full)
         _require_positive(plan.totals)
         schedule = _bill_dis(ds.T, m, plan, ledger, r1_payload)
         with trace.span("health"):
@@ -565,7 +573,7 @@ def _exec_materialized(
     with trace.span("health"):
         health = health_from_masses(np.asarray(scores))
     with trace.span("dis"):
-        plan = dis_plan_full(dis_key, scores, m)
+        plan = dis_plan_compiled(dis_key, scores, m, core=dis_plan_full)
     _require_positive(plan.totals)
     # rounds 2-3 exhaust hard even under degrade: by now the scores exist
     # and dropping a party would orphan its drawn rows (documented)
@@ -597,11 +605,12 @@ def _exec_fused(
     :func:`dis_plan_full` in ONE jitted dispatch, cached per ``(task,
     shapes, backend, params)``.
 
-    The eager :func:`_exec_materialized` stays the bit-identity anchor;
-    whole-program fusion may reorder fp reductions, so weights agree to fp
-    tolerance (not bitwise) and a draw landing exactly on a categorical
-    boundary could in principle differ — use the eager path where
-    cross-version draw stability matters.
+    :func:`_exec_materialized`'s jitted DIS core (scores computed eagerly
+    and the totals reduced eagerly before it) stays the bit-identity
+    anchor; whole-program fusion may reorder fp reductions, so weights agree
+    to fp tolerance (not bitwise) and a draw landing exactly on a
+    categorical boundary could in principle differ — use the materialized
+    engine where cross-version draw stability matters.
     """
     if spec.needs_labels and ds.y is None:
         raise ValueError(f"{spec.name} requires labels at party T")
@@ -871,8 +880,8 @@ def _exec_batched(
     """The batched engine: every (seed, budget) cell in one compiled
     ``jit(vmap(vmap(dis_plan_full)))`` call over the pure DIS core, using
     the ``m_cap`` prefix-masking convention for the budget grid.  For ``m
-    == m_cap`` each cell is exactly the eager :func:`_exec_materialized`
-    result for that key (eager hoisted totals keep the weight arithmetic
+    == m_cap`` each cell is exactly the :func:`_exec_materialized` result
+    for that key (eager hoisted totals keep the weight arithmetic
     bit-identical for deterministic-score tasks).
     """
     if spec.needs_labels and ds.y is None:

@@ -28,23 +28,36 @@ Layering (post api_redesign):
     convention).  Accounting is derived afterwards by
     :class:`repro.core.comm.CommSchedule` from ``(T, m)`` and the realised
     round-2 counts ``a_j`` the plan returns.
+  * :func:`dis_plan_compiled` — :func:`dis_plan_full` for concrete inputs,
+    as one jitted dispatch cached per shape.  The materialized engine and
+    :func:`dis_sample` call it; engines that are already inside a trace
+    (fused, batched, streamed, the service's merge tree) call
+    :func:`dis_plan_full` itself.
   * :func:`server_plan` — the one-round server-side variant used when the
     combined scores already live on every shard (the mesh selector's psum
     path: :mod:`repro.core.selector`).
   * :func:`dis_sample` / :func:`uniform_sample` — back-compat wrappers with
     the seed API (list-of-scores in, ledger recorded in place); they produce
     bit-identical ``(S, w)`` for the same PRNG key.
+
+:data:`GUMBEL_CHUNK_BYTES`, ``jax_enable_x64``, ``jax_threefry_partitionable``
+and ``jax_default_prng_impl`` are read when a function here is traced, not
+when it runs.  :func:`dis_plan_compiled` therefore fixes them per compiled
+program: its cache is keyed by the x64 and partitionable flags (JAX's own
+jit key) and by the chunk size (a static argument), but not by the default
+PRNG implementation, so change that one before the first draw.
 """
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Optional, Tuple, Union
+from typing import Callable, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.comm import CommLedger, CommSchedule
+from repro.utils import trace
 
 try:  # the head-draw replay reaches for the threefry primitive directly
     from jax._src.prng import threefry2x32_p as _threefry2x32_p
@@ -112,7 +125,8 @@ def _categorical_head(key_data, lg, cap: int, take: int):
 
 #: Per-party gumbel bytes above which :func:`_categorical_rows` draws the
 #: round-2 candidates in row chunks (the paper's YearPredictionMSD at
-#: m=2048 would otherwise hold 4.2 GB of noise per party).
+#: m=2048 would otherwise hold 4.2 GB of noise per party).  Read at trace
+#: time; :func:`dis_plan_compiled` keys its cache on it.
 GUMBEL_CHUNK_BYTES = 1 << 28
 
 
@@ -266,6 +280,36 @@ def dis_plan_full(
         S = jnp.where(valid, S, 0)
         w = jnp.where(valid, w, 0.0)
     return DisPlan(S, w, a, G_j)
+
+
+def _dis_core(core, key, scores, totals, m, chunk_bytes):
+    """The body :func:`dis_plan_compiled` jits.  ``chunk_bytes`` only keys
+    the cache: :func:`_categorical_rows` reads :data:`GUMBEL_CHUNK_BYTES`
+    while this traces.  ``dis_traces`` counts the traces on the open span,
+    so a warm build's ``repro.dis`` carries none."""
+    del chunk_bytes
+    trace.add(dis_traces=1)
+    return core(key, scores, m, totals=totals)
+
+
+_dis_plan_jit = jax.jit(_dis_core, static_argnames=("core", "m", "chunk_bytes"))
+
+
+def dis_plan_compiled(key: jax.Array, scores: jax.Array, m: int,
+                      core: Optional[Callable[..., DisPlan]] = None) -> DisPlan:
+    """:func:`dis_plan_full` at a static ``m`` as one jitted dispatch,
+    compiled once per shape, dtype and ``m``.
+
+    The per-party totals are reduced eagerly and passed in, as the batched
+    engine does, so G comes from the same reduction kernel as an eager
+    :func:`dis_plan_full` and the plan is bit-identical to it.  ``core`` is
+    the function traced, :func:`dis_plan_full` as this module binds it at
+    call time by default: a caller that binds its own name for the DIS core
+    passes what that name holds, and a substitute gets a program of its own.
+    """
+    totals = jnp.sum(scores.astype(_float_dtype()), axis=1)
+    return _dis_plan_jit(core or dis_plan_full, key, scores, totals, m=int(m),
+                         chunk_bytes=GUMBEL_CHUNK_BYTES)
 
 
 def split_uploads(indices, counts):
@@ -452,7 +496,8 @@ def dis_sample(
     m: int,
     ledger: Optional[CommLedger] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Run Algorithm 1 (DIS) — seed-compatible wrapper over :func:`dis_plan`.
+    """Run Algorithm 1 (DIS) — seed-compatible wrapper over
+    :func:`dis_plan_compiled`, the materialized engine's compiled draw.
 
     Args:
       key: PRNG key.
@@ -466,7 +511,7 @@ def dis_sample(
     """
     T = len(local_scores)
     scores = jnp.stack([jnp.asarray(g) for g in local_scores])
-    plan = dis_plan_full(key, scores, int(m))
+    plan = dis_plan_compiled(key, scores, int(m))
     if not bool(plan.totals.sum() > 0):
         raise ValueError("DIS requires a positive total score")
     CommSchedule.dis(T, int(m), counts=np.asarray(plan.counts)).record(ledger)

@@ -316,6 +316,37 @@ def test_batched_uniform():
     np.testing.assert_allclose(np.asarray(cs.weights), ds.n / 30)
 
 
+@pytest.mark.parametrize("transport", [False, True], ids=["recorded", "null-fault"])
+def test_materialized_compiled_draw_matches_eager_core_row_chunked(
+        monkeypatch, transport):
+    """The materialized engine's compiled DIS core, with the round-2 draw
+    forced into row chunks, equals an eager ``dis_plan_full`` on the same
+    scores and key bit for bit, on the recorded path and through a
+    null-fault transport, and equals the batched engine's m == m_cap cell."""
+    from repro.core import CoresetPipeline, CoresetSpec, FaultPlan, Transport
+    from repro.core import dis
+
+    n, m = 1187, 41                                # a shape no other test draws
+    monkeypatch.setattr(dis, "GUMBEL_CHUNK_BYTES", 4 * n * 6)   # 6-row chunks
+    ds = _dataset(jax.random.PRNGKey(23), n=n)
+    key = jax.random.PRNGKey(24)
+    scores, dis_key = get_task("vrlr").score_fn(key, ds, backend="ref")
+    eager = dis_plan_full(dis_key, scores, m)
+
+    pipe = CoresetPipeline(ds)
+    tr = Transport(FaultPlan.none()) if transport else None
+    cs = pipe.build(pipe.plan(CoresetSpec(task="vrlr", budgets=m,
+                                          engine="materialized", backend="ref")),
+                    key=key, ledger=CommLedger(), transport=tr)
+    np.testing.assert_array_equal(np.asarray(cs.indices), np.asarray(eager.indices))
+    np.testing.assert_array_equal(np.asarray(cs.weights), np.asarray(eager.weights))
+
+    cell = build_coresets_batched("vrlr", ds, [m], keys=key[None],
+                                  backend="ref").coreset(0, 0)
+    np.testing.assert_array_equal(np.asarray(cell.indices), np.asarray(cs.indices))
+    np.testing.assert_array_equal(np.asarray(cell.weights), np.asarray(cs.weights))
+
+
 # --------------------------------------------------------------------------
 # Materialize accounting (Theorem 2.5's +2mT) and schedule composition
 # --------------------------------------------------------------------------

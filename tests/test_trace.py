@@ -134,6 +134,40 @@ def test_forced_recompile_is_marked_inside_its_span(tmp_path):
     assert probe[1] <= end - marks[-1][3]["secs"] * 1e9
 
 
+def test_warm_materialized_build_compiles_nothing(tmp_path):
+    """The materialized engine's DIS core is traced once per shape: a second
+    build of the same shapes compiles nothing and its ``repro.dis`` carries
+    no ``dis_traces``; a build at a new ``m`` traces the core once."""
+    X = jax.random.normal(jax.random.PRNGKey(5), (2311, 7))
+    ds = VFLDataset([X[:, 0:2], X[:, 2:4], X[:, 4:7]], X @ jnp.arange(7.0))
+    pipe = CoresetPipeline(ds)
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    compiles = []
+
+    def on_duration(event, secs, **_):
+        if event == trace.COMPILE_EVENT:
+            compiles.append(secs)
+
+    def build(k, m, where):
+        spec = CoresetSpec(task="vrlr", budgets=m, engine="materialized",
+                           backend="ref")
+        with jax.profiler.trace(str(where)):
+            pipe.build(pipe.plan(spec), key=k).indices.block_until_ready()
+        (thread,) = _spans(where)
+        (dis,) = [e for e in thread if e[0] == "repro.dis"]
+        return dis[3]
+
+    assert build(keys[0], 29, tmp_path / "cold").get("dis_traces") == 1
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        warm = build(keys[1], 29, tmp_path / "warm")
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+    assert "dis_traces" not in warm and "compiles" not in warm
+    assert build(keys[2], 30, tmp_path / "new_m").get("dis_traces") == 1
+
+
 def test_add_sums_into_the_innermost_span(tmp_path):
     with jax.profiler.trace(str(tmp_path)):
         with trace.span("outer", tag="a"):

@@ -86,7 +86,7 @@ from repro.core.sensitivity import (
     vrlr_scores_stacked,
 )
 from repro.core.vfl import VFLDataset
-from repro.core.vkmc import kmeans
+from repro.core.vkmc import kmeans_plusplus, lloyd
 from repro.core.wire import WirePayload, get_codec
 from repro.utils import trace
 from repro.utils.registry import Registry
@@ -207,14 +207,20 @@ def vkmc_scores(key, ds: VFLDataset, backend: str = "pallas",
                 k: int = 10, alpha: float = 2.0, local_iters: int = 15):
     """Algorithm 3: party j runs local k-means (alpha-approximate) and scores
     its block; the key is split once per party and once more for DIS —
-    exactly the seed's chain (subkeys are pre-split host-side, then the
-    compute runs as ONE vmap over the party axis of the stacked view).
+    exactly the seed's chain (subkeys are pre-split host-side, then
+    seeding, Lloyd and scoring each run as one vmap over the party axis of
+    the stacked view).
 
     Zero column padding is distance-transparent (every point shares the
     same zeros), so local k-means and sensitivities on the padded blocks
     equal their per-party values.  ``alpha`` is the approximation factor
     credited to the local solver (k-means++ + Lloyd is O(log k) in theory,
     ~2 in practice).
+
+    Seeding, Lloyd and scoring are the spans ``score.seed``,
+    ``score.lloyd`` and ``score.sens``; ``lloyd_passes`` on the open span
+    counts the fused assign-update passes over X: ``local_iters`` Lloyd
+    passes and one scoring pass per party.
     """
     subs = []
     for _ in range(ds.T):                     # the seed's per-party key chain
@@ -223,15 +229,21 @@ def vkmc_scores(key, ds: VFLDataset, backend: str = "pallas",
     key, dis_key = jax.random.split(key)
     use_kernel = _use_kernel(backend)
 
-    def party(sub, Xb):
-        local_c = kmeans(sub, Xb, k, iters=local_iters, use_kernel=use_kernel)
-        return vkmc_local_scores(Xb, local_c, alpha, use_kernel=use_kernel)
-
     with jax.named_scope("score"):
         st = ds.stacked()
         if backend == "norm":
             return norm_scores(st.blocks) + 1.0 / ds.n, dis_key
-        return jax.vmap(party)(jnp.stack(subs), st.blocks), dis_key
+        trace.add(lloyd_passes=ds.T * (local_iters + 1))
+        with trace.span("score.seed"):
+            init = jax.vmap(lambda s, X: kmeans_plusplus(s, X, k))(
+                jnp.stack(subs), st.blocks)
+        with trace.span("score.lloyd"):
+            centers = jax.vmap(lambda X, c: lloyd(
+                X, c, iters=local_iters, use_kernel=use_kernel))(st.blocks, init)
+        with trace.span("score.sens"):
+            scores = jax.vmap(lambda X, c: vkmc_local_scores(
+                X, c, alpha, use_kernel=use_kernel))(st.blocks, centers)
+        return scores, dis_key
 
 
 CORESET_TASKS.register("uniform")(

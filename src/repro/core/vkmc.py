@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.comm import CommLedger, null_ledger
-from repro.core.sensitivity import kmeans_assignment, kmeans_update
+from repro.core.sensitivity import HIGHEST, kmeans_assignment, kmeans_update
 from repro.core.vfl import VFLDataset
 
 
@@ -46,7 +46,11 @@ def kmeans_plusplus(
     ``||x - c||^2 = ||x||^2 - 2 x.c + ||c||^2``: the per-step cost is one
     (n, d) matvec instead of materialising the full (n, d) difference —
     one fewer (n, d) array per seeding step, and the row norms ``||x||^2``
-    are computed once for the whole sweep.
+    are computed once for the whole sweep.  The matvec runs at HIGHEST,
+    as every distance dot of the package does: the TPU's DEFAULT (one bf16
+    pass) rounds the cross term by about 2^-9 (see
+    :data:`repro.core.sensitivity.HIGHEST`), enough to change which row a
+    D^2 draw takes at a near-tie.
     """
     n, d = X.shape
     ww = jnp.ones((n,)) if w is None else jnp.maximum(w, 0.0)
@@ -54,7 +58,8 @@ def kmeans_plusplus(
 
     def d2_to(c):
         # clamp: the expanded form can go slightly negative under fp
-        return jnp.maximum(x2 - 2.0 * (X @ c) + jnp.sum(c * c), 0.0)
+        return jnp.maximum(x2 - 2.0 * jnp.matmul(X, c, precision=HIGHEST)
+                           + jnp.sum(c * c), 0.0)
 
     k0, key = jax.random.split(key)
     first = jax.random.categorical(k0, jnp.log(jnp.maximum(ww, 1e-30)))

@@ -6,6 +6,8 @@ import numpy as np
 
 from repro.core import (
     CommLedger,
+    CoresetPipeline,
+    CoresetSpec,
     VFLDataset,
     build_coresets_batched,
     build_uniform_coreset,
@@ -15,6 +17,7 @@ from repro.core import (
     kmeans_cost,
     vkmc_coreset_ratio,
 )
+from repro.core.vkmc import kmeans_plusplus
 from repro.data.synthetic import correlated_vfl_data
 
 
@@ -102,3 +105,56 @@ def test_coreset_comm_much_smaller_than_distdim():
     build_vkmc_coreset(jax.random.PRNGKey(12), ds, k=k, m=200, ledger=led_cs)
     distdim(jax.random.PRNGKey(13), ds, k, ledger=led_dd)
     assert led_cs.total < led_dd.total / 5
+
+
+def _dot_precisions(jaxpr):
+    """``precision`` of every ``dot_general`` in a jaxpr and its sub-jaxprs."""
+    from jax.extend import core
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, core.ClosedJaxpr):
+                    out += _dot_precisions(sub.jaxpr)
+                elif isinstance(sub, core.Jaxpr):
+                    out += _dot_precisions(sub)
+    return out
+
+
+def test_kmeans_plusplus_distances_run_at_highest_precision():
+    """The seeding's D^2 distances: at the TPU's DEFAULT precision (one
+    bf16 pass) a draw at a near-tie can take another row than float64."""
+    X = jax.random.normal(jax.random.PRNGKey(0), (257, 9))
+    jaxpr = jax.make_jaxpr(lambda key, X: kmeans_plusplus(key, X, 5))(
+        jax.random.PRNGKey(1), X)
+    precisions = _dot_precisions(jaxpr.jaxpr)
+    assert precisions
+    highest = jax.lax.Precision.HIGHEST
+    assert all(p is not None and all(q == highest for q in p) for p in precisions), \
+        precisions
+
+
+def test_vkmc_materialized_draw_is_unchanged_on_the_cpu():
+    """A recorded build: the spans around seeding, Lloyd and scoring and
+    the HIGHEST seeding dot change no draw and no weight on the CPU, where
+    DEFAULT is already full float32."""
+    X = jax.random.normal(jax.random.PRNGKey(21), (2000, 12)) * jnp.linspace(0.5, 2.0, 12)
+    ds = VFLDataset([X[:, 0:4], X[:, 4:8], X[:, 8:12]], None)
+    spec = CoresetSpec(task="vkmc", budgets=24, engine="materialized", backend="ref",
+                       params={"k": 5, "alpha": 2.0, "local_iters": 4})
+    pipe = CoresetPipeline(ds)
+    led = CommLedger()
+    cs = pipe.build(pipe.plan(spec), key=jax.random.PRNGKey(5), ledger=led)
+    S, w = np.asarray(cs.indices), np.asarray(cs.weights)
+    assert S.tolist() == [1357, 1607, 1906, 1196, 289, 114, 1589, 1738, 1723, 1910, 589,
+                          157, 1351, 222, 1616, 27, 606, 954, 961, 1018, 1045, 1984, 385,
+                          1140]
+    assert w.view(np.uint32).tolist() == [
+        1118534984, 1117660448, 1118121366, 1117995357, 1117870634, 1119293047,
+        1116535405, 1118795117, 1118090048, 1118315365, 1117534650, 1117422857,
+        1118352385, 1116730821, 1119128305, 1118647721, 1118611351, 1116944168,
+        1117462232, 1118582738, 1118658953, 1117260517, 1118234917, 1119646229]
+    assert led.total == 174

@@ -94,6 +94,29 @@ def test_materialized_build_span_tree(tmp_path):
     assert len(plans) == 1 and plans[0][2] <= b[1]
 
 
+def test_vkmc_materialized_score_spans(tmp_path):
+    """Algorithm 3's scoring opens ``score.seed``, ``.lloyd`` and ``.sens``
+    inside the engine's ``repro.score``, and counts the fused Lloyd passes
+    over X there: T x (local_iters + 1)."""
+    ds = _data(host=False)
+    ds = VFLDataset(ds.parts, None)
+    spec = CoresetSpec(task="vkmc", budgets=32, engine="materialized", backend="ref",
+                       params={"k": 4, "local_iters": 3})
+    pipe = CoresetPipeline(ds)
+    keys = jax.random.split(jax.random.PRNGKey(8), 2)
+    pipe.build(pipe.plan(spec), key=keys[0]).indices.block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        pipe.build(pipe.plan(spec), key=keys[1]).indices.block_until_ready()
+    (thread,) = _spans(tmp_path)
+    (b,) = [e for e in thread if e[0] == "repro.build"]
+    (score,) = _children(thread, b, "repro.score")
+    assert score[3]["lloyd_passes"] == ds.T * (3 + 1)
+    got = [e for e in thread if e[0].startswith("repro.score.")]
+    assert [e[0] for e in sorted(got, key=lambda e: e[1])] == [
+        "repro.score.seed", "repro.score.lloyd", "repro.score.sens"]
+    assert all(_inside(e, score) for e in got)
+
+
 def test_pipelined_build_span_tree(tmp_path):
     keys = jax.random.split(jax.random.PRNGKey(4), 2)
     _build("pipelined", keys[0])

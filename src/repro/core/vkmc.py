@@ -34,6 +34,7 @@ def kmeans_cost(
     return jnp.sum(d2 if w is None else w * d2)
 
 
+@functools.partial(jax.jit, static_argnames=("k",))
 def kmeans_plusplus(
     key: jax.Array,
     X: jax.Array,
@@ -51,6 +52,10 @@ def kmeans_plusplus(
     pass) rounds the cross term by about 2^-9 (see
     :data:`repro.core.sensitivity.HIGHEST`), enough to change which row a
     D^2 draw takes at a near-tie.
+
+    Jitted like :func:`lloyd`: one compiled program per shape and ``k``,
+    where an eager call (or an eager ``vmap`` of one) would trace and
+    lower the seeding's scan anew on every call.
     """
     n, d = X.shape
     ww = jnp.ones((n,)) if w is None else jnp.maximum(w, 0.0)

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (
     CommLedger,
@@ -135,6 +136,32 @@ def test_kmeans_plusplus_distances_run_at_highest_precision():
     highest = jax.lax.Precision.HIGHEST
     assert all(p is not None and all(q == highest for q in p) for p in precisions), \
         precisions
+
+
+@pytest.mark.parametrize("shape", [(257, 9), (1031, 4)])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_jitted_kmeans_plusplus_equals_the_eager_body(shape, weighted):
+    """The compiled seeding draws the rows the op-by-op body draws, bit for
+    bit: alone, weighted or not, and vmapped over three parties as
+    ``vkmc_scores`` calls it."""
+    X = jax.random.normal(jax.random.PRNGKey(shape[0]), shape) * jnp.linspace(0.5, 2.0, shape[1])
+    w = (jax.random.uniform(jax.random.PRNGKey(7), shape[:1]) * 3.0).at[::5].set(0.0) \
+        if weighted else None
+    key = jax.random.PRNGKey(31)
+    subs = jax.random.split(jax.random.PRNGKey(32), 3)
+    blocks = jnp.stack([X, X[::-1], X * 0.5])
+
+    def run():
+        one = kmeans_plusplus(key, X, 6, w)
+        three = jax.vmap(lambda s, B: kmeans_plusplus(s, B, 6, w))(subs, blocks)
+        return np.asarray(one), np.asarray(three)
+
+    one, three = run()
+    with jax.disable_jit():
+        one_eager, three_eager = run()
+    assert one.shape == (6, shape[1]) and three.shape == (3, 6, shape[1])
+    np.testing.assert_array_equal(one.view(np.uint32), one_eager.view(np.uint32))
+    np.testing.assert_array_equal(three.view(np.uint32), three_eager.view(np.uint32))
 
 
 def test_vkmc_materialized_draw_is_unchanged_on_the_cpu():

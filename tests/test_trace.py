@@ -191,6 +191,41 @@ def test_warm_materialized_build_compiles_nothing(tmp_path):
     assert build(keys[2], 30, tmp_path / "new_m").get("dis_traces") == 1
 
 
+def test_warm_vkmc_seeding_compiles_nothing(tmp_path):
+    """Algorithm 3's k-means++ seeding is compiled once per shape and ``k``:
+    a second build of the same shapes compiles nothing and its
+    ``repro.score.seed`` carries no ``compiles``; a build at a new ``k``
+    compiles the seeding again."""
+    X = jax.random.normal(jax.random.PRNGKey(12), (2203, 8))
+    ds = VFLDataset([X[:, 0:3], X[:, 3:5], X[:, 5:8]], None)
+    pipe = CoresetPipeline(ds)
+    keys = jax.random.split(jax.random.PRNGKey(13), 3)
+    compiles = []
+
+    def on_duration(event, secs, **_):
+        if event == trace.COMPILE_EVENT:
+            compiles.append(secs)
+
+    def build(key, k, where):
+        spec = CoresetSpec(task="vkmc", budgets=19, engine="materialized",
+                           backend="ref", params={"k": k, "local_iters": 2})
+        with jax.profiler.trace(str(where)):
+            pipe.build(pipe.plan(spec), key=key).indices.block_until_ready()
+        (thread,) = _spans(where)
+        (seed,) = [e for e in thread if e[0] == "repro.score.seed"]
+        return seed[3]
+
+    assert build(keys[0], 3, tmp_path / "cold").get("compiles", 0) >= 1
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        warm = build(keys[1], 3, tmp_path / "warm")
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+    assert "compiles" not in warm
+    assert build(keys[2], 4, tmp_path / "new_k").get("compiles", 0) >= 1
+
+
 def test_add_sums_into_the_innermost_span(tmp_path):
     with jax.profiler.trace(str(tmp_path)):
         with trace.span("outer", tag="a"):

@@ -5,7 +5,7 @@ Public API:
   CoresetSpec, ExecutionPlan, compile_plan, ENGINES,
   PlanCache                                               (plan — declarative spec
                                                            + auto-planner)
-  CoresetPipeline, build_coreset, build_coreset_jit,
+  CoresetPipeline, build_coreset,
   build_coresets_batched, build_coreset_streaming,
   CoresetTask, register_task, get_task,
   CORESET_TASKS, SCORE_BACKENDS, resolve_backend          (api — spec-compiled engines)
@@ -55,7 +55,6 @@ from repro.core.api import (
     FailoverAttempt,
     FailoverOutcome,
     build_coreset,
-    build_coreset_jit,
     build_coreset_streaming,
     build_coresets_batched,
     get_task,

@@ -19,13 +19,16 @@ DIS sampling -> importance weights — which this module makes explicit:
     plan.  ``pipeline.plan(spec).describe()`` shows every planner decision
     (engine, clamps, predicted peak bytes, predicted comm units) before
     anything runs.
-  * The four legacy entry points — :func:`build_coreset` (materialized),
-    :func:`build_coreset_jit` (materialized, fused one-dispatch),
+  * The three legacy entry points — :func:`build_coreset` (materialized),
     :func:`build_coreset_streaming` (streamed/pipelined), and
     :func:`build_coresets_batched` (batched) — are thin shims constructing
     forced-engine specs; each is DRAW-IDENTICAL to the same spec through
     ``CoresetPipeline.build`` (same code path, pinned by
     ``tests/test_plan.py``).
+  * The single-cell engines (materialized, streamed, pipelined) run
+    Algorithm 1's rounds through ONE protocol body, :func:`_build_one`;
+    each engine supplies only its scoring, its round-1 mass table and its
+    draw.
 
 Key-consumption choreography matches the seed builders exactly, so the
 deprecated ``build_vrlr_coreset`` / ``build_vkmc_coreset`` shims in
@@ -253,9 +256,8 @@ CORESET_TASKS.register("uniform")(
 
 
 # --------------------------------------------------------------------------
-# Engine executors — one per ExecutionPlan.engine.  These are the exact
-# legacy builder bodies, factored so the shims and the pipeline share ONE
-# code path (draw identity by construction, pinned by tests/test_plan.py).
+# Engine executors.  The shims and the pipeline share ONE code path per
+# engine (draw identity by construction, pinned by tests/test_plan.py).
 # --------------------------------------------------------------------------
 
 def _policy_retries(fault_policy: str) -> Optional[int]:
@@ -500,168 +502,6 @@ def _bill_dis(T: int, m: int, plan, ledger: Optional[CommLedger],
     return schedule
 
 
-def _exec_materialized(
-    spec: CoresetTask, ds: VFLDataset, m: int, key, backend: str,
-    ledger: Optional[CommLedger], params: dict,
-    transport: Optional[Transport] = None, fault_policy: str = "fail",
-    codec: str = "raw_fp32",
-) -> Coreset:
-    """The sequential engine — the fidelity reference against the seed's
-    builders: scores computed eagerly, then DIS on the full matrix as one
-    compiled dispatch (:func:`dis_plan_compiled`, bit-identical to an eager
-    :func:`dis_plan_full`).
-
-    With a ``transport`` the DIS rounds are DELIVERED instead of recorded:
-    round 1 before scoring (where ``degrade`` can still drop a party —
-    sensitivities are then recomputed over the surviving feature slices),
-    rounds 2-3 after the draw.  Without one (or with a null fault plan) the
-    ledger entries and draws are bit-identical to the pre-transport path.
-    """
-    if spec.needs_labels and ds.y is None:
-        raise ValueError(f"{spec.name} requires labels at party T")
-    retries = _policy_retries(fault_policy)
-    if spec.score_fn is None:
-        S, w = uniform_plan(key, ds.n, m)
-        schedule = CommSchedule.uniform(ds.T, m)
-        if transport is None:
-            schedule.record(ledger)
-            return Coreset(S, w, schedule.total,
-                           comm_bits=schedule.total_bits)
-        rep = transport.deliver(schedule, ledger, max_retries=retries,
-                                drop_on_exhaust=(fault_policy == "degrade"))
-        degraded = None
-        if rep.failed:
-            dropped = tuple(sorted(rep.failed.values(), key=lambda d: d.party))
-            alive = sorted(set(range(ds.T)) - set(rep.failed))
-            degraded = DegradedBuild(dropped=dropped, surviving=tuple(alive),
-                                     total_parties=ds.T)
-        return Coreset(S, w, rep.units, comm_bits=rep.bits,
-                       degraded=degraded)
-
-    # the round-1 G_j upload physically carries the per-row mass table —
-    # one float32 entry per row on this engine, descriptor shared by the
-    # recorded and the delivered path so their bits columns agree
-    r1_payload = WirePayload.of((ds.n,), "float32", codec)
-    if transport is None:
-        if codec != "raw_fp32":
-            raise ValueError(
-                f"codec={codec!r} quantizes what crosses the wire; without "
-                f"a transport nothing crosses it — the recorded path "
-                f"supports codec='raw_fp32' only"
-            )
-        with trace.span("score"):
-            scores, dis_key = spec.score_fn(key, ds, backend=backend, **params)
-        with trace.span("dis"):
-            plan = dis_plan_compiled(dis_key, scores, m, core=dis_plan_full)
-        _require_positive(plan.totals)
-        schedule = _bill_dis(ds.T, m, plan, ledger, r1_payload)
-        with trace.span("health"):
-            health = health_from_masses(np.asarray(scores))
-        return Coreset(plan.indices, plan.weights, schedule.total,
-                       comm_bits=schedule.total_bits, health=health)
-
-    eff_ds, alive, degraded, units1, bits1 = _faulted_round1(
-        spec, ds, transport, ledger, fault_policy, payload=r1_payload)
-    with trace.span("score"):
-        scores, dis_key = spec.score_fn(key, eff_ds, backend=backend, **params)
-    # integrity seam: the per-row score table IS this engine's round-1 mass
-    # payload — ship it under envelopes, validate what arrived
-    delivered, offenders, ship_units, ship_bits = _integrity_round1(
-        spec, eff_ds, transport, ledger, fault_policy,
-        np.asarray(scores), backend, params, codec=codec)
-    if offenders:
-        eff_ds, alive, degraded = _quarantine(spec, ds, alive, degraded,
-                                              offenders)
-        # rescore the survivors; their tables already validated clean
-        with trace.span("score"):
-            scores, dis_key = spec.score_fn(key, eff_ds, backend=backend,
-                                            **params)
-    elif delivered is not None:
-        # what crossed the wire drives the draw: a lossy codec's quantized
-        # table on the clean path, or — with verification off — corrupted
-        # masses, exactly the undefended blow-up the integrity benchmark
-        # measures
-        scores = jnp.asarray(delivered)
-    with trace.span("health"):
-        health = health_from_masses(np.asarray(scores))
-    with trace.span("dis"):
-        plan = dis_plan_compiled(dis_key, scores, m, core=dis_plan_full)
-    _require_positive(plan.totals)
-    # rounds 2-3 exhaust hard even under degrade: by now the scores exist
-    # and dropping a party would orphan its drawn rows (documented)
-    up_payloads, up_blobs = _round2_wire(plan, alive, ds.T, codec)
-    rep23 = transport.deliver(
-        CommSchedule.dis_rounds23(ds.T, m, counts=np.asarray(plan.counts),
-                                  parties=alive,
-                                  upload_payloads=up_payloads),
-        ledger, max_retries=retries, drop_on_exhaust=False,
-    )
-    indices, r2_units, r2_bits = _ship_round2(
-        transport, ledger, fault_policy, plan, alive, ds.T,
-        codec=codec, blobs=up_blobs)
-    return Coreset(indices, plan.weights,
-                   units1 + rep23.units + ship_units + r2_units,
-                   comm_bits=bits1 + rep23.bits + ship_bits + r2_bits,
-                   degraded=degraded, health=health)
-
-
-# (task spec, dims, labeled?, n, m, backend, params) -> jitted builder.
-_JIT_BUILDERS: dict = {}
-
-
-def _exec_fused(
-    spec: CoresetTask, ds: VFLDataset, m: int, key, backend: str,
-    ledger: Optional[CommLedger], params: dict,
-) -> Coreset:
-    """The materialized engine's fused fast path: scoring +
-    :func:`dis_plan_full` in ONE jitted dispatch, cached per ``(task,
-    shapes, backend, params)``.
-
-    :func:`_exec_materialized`'s jitted DIS core (scores computed eagerly
-    and the totals reduced eagerly before it) stays the bit-identity
-    anchor; whole-program fusion may reorder fp reductions, so weights agree
-    to fp tolerance (not bitwise) and a draw landing exactly on a
-    categorical boundary could in principle differ — use the materialized
-    engine where cross-version draw stability matters.
-    """
-    if spec.needs_labels and ds.y is None:
-        raise ValueError(f"{spec.name} requires labels at party T")
-
-    if spec.score_fn is None:
-        cache_key = (spec, ds.n, m)
-        fn = _JIT_BUILDERS.get(cache_key)
-        if fn is None:
-            n = ds.n   # bind the scalars only — the cached closure must not
-            fn = jax.jit(lambda k: uniform_plan(k, n, m))  # pin ds's arrays
-            _JIT_BUILDERS[cache_key] = fn
-        S, w = fn(key)
-        schedule = CommSchedule.uniform(ds.T, m)
-        schedule.record(ledger)
-        return Coreset(S, w, schedule.total, comm_bits=schedule.total_bits)
-
-    cache_key = (spec, ds.dims, ds.y is not None, ds.n, m, backend,
-                 tuple(sorted(params.items())))
-    fn = _JIT_BUILDERS.get(cache_key)
-    if fn is None:
-        def _build(k, parts, y):
-            ds_t = VFLDataset(list(parts), y)
-            scores, dis_key = spec.score_fn(k, ds_t, backend=backend, **params)
-            return dis_plan_full(dis_key, scores, m)
-
-        fn = jax.jit(_build)
-        _JIT_BUILDERS[cache_key] = fn
-    plan = fn(key, tuple(ds.parts), ds.y)
-    _require_positive(plan.totals)
-    schedule = _bill_dis(ds.T, m, plan, ledger,
-                         WirePayload.of((ds.n,), "float32", "raw_fp32"))
-    return Coreset(plan.indices, plan.weights, schedule.total,
-                   comm_bits=schedule.total_bits)
-
-
-# sharded block-mass helpers per task (the `sharded_masses` plan toggle)
-_SHARDED_MASSES: dict = {}
-
-
 def _sharded_mass_table(task_name: str, key, ds: VFLDataset,
                         block_size: int, backend: str, params: dict):
     """Compute the (T, nb) block-mass table data-parallel over a one-axis
@@ -691,42 +531,156 @@ def _sharded_mass_table(task_name: str, key, ds: VFLDataset,
     )
 
 
-def _exec_streaming(
-    spec: CoresetTask, ds: VFLDataset, m: int, key, backend: str,
-    ledger: Optional[CommLedger], probe, block_size: int, chunk_blocks: int,
-    prefetch: bool, pipelined: bool, sharded_masses: bool, params: dict,
-    transport: Optional[Transport] = None, fault_policy: str = "fail",
-    checkpoint: Optional[StreamCheckpoint] = None,
-    codec: str = "raw_fp32",
+@dataclasses.dataclass
+class _Engine:
+    """What a single-cell engine contributes to :func:`_build_one`; the
+    protocol rounds around it are the same for every engine.  A subclass
+    supplies ``cells(ds)`` (round-1 table entries per party),
+    ``score(ds)`` (a scoring state), ``table(state)`` (the round-1 mass
+    table it ships), ``with_table(state, table)`` (the same state drawing
+    from a delivered table), ``draw(state, m)`` (a DIS plan, positivity
+    checked) and ``health(state)``."""
+
+    task: CoresetTask
+    key: jax.Array
+    backend: str
+    params: dict
+
+
+class _MaterializedEngine(_Engine):
+    """Per-row scores: the task's (T, n) score table, drawn from by ONE
+    compiled dispatch (:func:`dis_plan_compiled`, bit-identical to an eager
+    :func:`dis_plan_full`).  The state is ``(scores, dis_key)``."""
+
+    def cells(self, ds: VFLDataset) -> int:
+        return ds.n
+
+    def score(self, ds: VFLDataset):
+        return self.task.score_fn(self.key, ds, backend=self.backend,
+                                  **self.params)
+
+    def table(self, state):
+        return state[0]
+
+    def with_table(self, state, table):
+        return jnp.asarray(table), state[1]
+
+    def draw(self, state, m: int):
+        scores, dis_key = state
+        with trace.span("dis"):
+            plan = dis_plan_compiled(dis_key, scores, m, core=dis_plan_full)
+        _require_positive(plan.totals)
+        return plan
+
+    def health(self, state) -> HealthReport:
+        return health_from_masses(np.asarray(state[0]))
+
+
+@dataclasses.dataclass
+class _StreamedEngine(_Engine):
+    """Block-scan scoring and the hierarchical (party, block) draw: the
+    state is a :class:`~repro.core.streaming.StreamScorer` whose (T, nb)
+    block-mass table is the round-1 payload.  ``pipelined`` selects the
+    superchunk-grouped redraw
+    (:func:`repro.core.streaming.dis_plan_streamed_batched`) — the same
+    draws as the block-at-a-time reference, fewer dispatches.  All knobs
+    arrive RESOLVED (validated by :class:`CoresetSpec`, clamped by the
+    planner).
+
+    ``checkpoint`` makes the scorer's scan passes resumable per
+    superchunk: a crashed build rerun with the same arguments restores the
+    last completed superchunk's accumulators and finishes
+    draw-identically."""
+
+    m: int
+    probe: Optional[Callable[[], None]]
+    block_size: int
+    chunk_blocks: int
+    prefetch: bool
+    pipelined: bool
+    sharded_masses: bool
+    checkpoint: Optional[StreamCheckpoint]
+
+    def cells(self, ds: VFLDataset) -> int:
+        return ds.block_geometry(int(self.block_size))[0]
+
+    def score(self, ds: VFLDataset):
+        from repro.core.streaming import make_stream_scorer
+
+        masses = None
+        if self.sharded_masses:
+            # task/backend compatibility was validated by compile_plan —
+            # every path into this engine goes through the planner
+            masses = _sharded_mass_table(self.task.name, self.key, ds,
+                                         self.block_size, self.backend,
+                                         self.params)
+        if self.checkpoint is not None:
+            self.checkpoint.bind((
+                self.task.name, ds.n, ds.dims, ds.y is not None,
+                int(self.block_size), int(self.chunk_blocks),
+                bool(self.prefetch), self.backend,
+                tuple(sorted(self.params.items())), int(self.m),
+                tuple(np.asarray(_key_data(self.key)).ravel().tolist()),
+            ))
+        return make_stream_scorer(self.task.name, self.key, ds,
+                                  int(self.block_size), self.backend,
+                                  probe=self.probe,
+                                  chunk_blocks=self.chunk_blocks,
+                                  prefetch=self.prefetch, masses=masses,
+                                  ckpt=self.checkpoint, **self.params)
+
+    def table(self, scorer):
+        return scorer.masses
+
+    def with_table(self, scorer, table):
+        from repro.core.streaming import with_masses
+
+        return with_masses(scorer, table)
+
+    def draw(self, scorer, m: int):
+        from repro.core.streaming import (
+            dis_plan_streamed,
+            dis_plan_streamed_batched,
+        )
+
+        _require_positive(scorer.masses)
+        with trace.span("dis"):
+            if self.pipelined:
+                plan = dis_plan_streamed_batched(scorer, m, probe=self.probe)
+            else:
+                plan = dis_plan_streamed(scorer, m, probe=self.probe)
+        if self.checkpoint is not None:
+            self.checkpoint.clear()            # the build completed
+        return plan
+
+    def health(self, scorer) -> HealthReport:
+        return health_from_masses(np.asarray(scorer.masses),
+                                  gram_conds=scorer.gram_conds)
+
+
+def _build_one(
+    engine: _Engine, ds: VFLDataset, m: int, ledger: Optional[CommLedger],
+    transport: Optional[Transport], fault_policy: str, codec: str,
 ) -> Coreset:
-    """The streamed / pipelined engines: block-scan scoring + hierarchical
-    (party, block) DIS.  ``pipelined`` selects the superchunk-grouped
-    redraw (:func:`repro.core.streaming.dis_plan_streamed_batched`) — the
-    same draws as the block-at-a-time reference, fewer dispatches.  All
-    knobs arrive RESOLVED (validated by :class:`CoresetSpec`, clamped by
-    the planner) — nothing is coerced here.
+    """Algorithm 1 for one coreset on a single-cell engine.
 
-    ``transport`` delivers the DIS rounds through the fault seam exactly as
-    in :func:`_exec_materialized` (round 1 before the scorer is built, so
-    ``degrade`` drops a party before any pass over the data).
-    ``checkpoint`` (a :class:`~repro.core.faults.StreamCheckpoint`) makes
-    the scorer's scan passes resumable per superchunk: a crashed build
-    rerun with the same arguments restores the last completed superchunk's
-    accumulators and finishes draw-identically.  ``None`` for either keeps
-    today's exact code path.
+    Without a ``transport`` the DIS rounds are RECORDED on ``ledger`` from
+    the realised plan.  With one they are DELIVERED: round 1 before
+    scoring, where ``degrade`` can still drop a party (sensitivities are
+    then computed over the surviving feature slices); then the scored mass
+    table crosses the wire under envelopes, and what was delivered drives
+    the draw; rounds 2-3 after the draw.  With a null fault plan the draws
+    and ledger entries are bit-identical to the recorded path.
+
+    The health copy of the mass table runs after the draw is dispatched,
+    so on the materialized engine it overlaps the draw on the device.
     """
-    from repro.core.streaming import (
-        dis_plan_streamed,
-        dis_plan_streamed_batched,
-        make_stream_scorer,
-        with_masses,
-    )
-
-    if spec.needs_labels and ds.y is None:
-        raise ValueError(f"{spec.name} requires labels at party T")
+    task = engine.task
+    if task.needs_labels and ds.y is None:
+        raise ValueError(f"{task.name} requires labels at party T")
     retries = _policy_retries(fault_policy)
-    if spec.score_fn is None:
-        S, w = uniform_plan(key, ds.n, m)
+    if task.score_fn is None:
+        S, w = uniform_plan(engine.key, ds.n, m)
         schedule = CommSchedule.uniform(ds.T, m)
         if transport is None:
             schedule.record(ledger)
@@ -743,89 +697,65 @@ def _exec_streaming(
         return Coreset(S, w, rep.units, comm_bits=rep.bits,
                        degraded=degraded)
 
-    # the streamed round-1 payload is the (T, nb) block-mass table — one
-    # float32 entry per BLOCK per party, not per row
-    nb = ds.block_geometry(int(block_size))[0]
-    r1_payload = WirePayload.of((nb,), "float32", codec)
     if transport is None and codec != "raw_fp32":
         raise ValueError(
             f"codec={codec!r} quantizes what crosses the wire; without a "
             f"transport nothing crosses it — the recorded path supports "
             f"codec='raw_fp32' only"
         )
-    alive = degraded = None
-    units1 = bits1 = 0
-    eff_ds = ds
+    # the round-1 G_j upload physically carries the party's mass table —
+    # one float32 entry per scoring cell (a row, or a block on the streaming
+    # engines); one descriptor for the recorded and the delivered path so
+    # their bits columns agree
+    r1_payload = WirePayload.of((engine.cells(ds),), "float32", codec)
+    eff_ds, alive, degraded, units, bits = ds, None, None, 0, 0
     if transport is not None:
-        eff_ds, alive, degraded, units1, bits1 = _faulted_round1(
-            spec, ds, transport, ledger, fault_policy, payload=r1_payload)
-
-    def _build_scorer(eff):
-        masses = None
-        if sharded_masses:
-            # task/backend compatibility was validated by compile_plan —
-            # every path into this executor goes through the planner
-            masses = _sharded_mass_table(spec.name, key, eff, block_size,
-                                         backend, params)
-        if checkpoint is not None:
-            checkpoint.bind((
-                spec.name, eff.n, eff.dims, eff.y is not None,
-                int(block_size), int(chunk_blocks), bool(prefetch), backend,
-                tuple(sorted(params.items())), int(m),
-                tuple(np.asarray(_key_data(key)).ravel().tolist()),
-            ))
-        return make_stream_scorer(spec.name, key, eff, int(block_size),
-                                  backend, probe=probe,
-                                  chunk_blocks=chunk_blocks,
-                                  prefetch=prefetch, masses=masses,
-                                  ckpt=checkpoint, **params)
-
+        eff_ds, alive, degraded, units, bits = _faulted_round1(
+            task, ds, transport, ledger, fault_policy, payload=r1_payload)
     with trace.span("score"):
-        scorer = _build_scorer(eff_ds)
-    ship_units = ship_bits = 0
+        state = engine.score(eff_ds)
     if transport is not None:
-        # integrity seam: the (T, nb) block-mass table is the streamed
-        # round-1 payload — ship it under envelopes, validate what arrived
+        # integrity seam: ship the mass table under envelopes, validate
+        # what arrived
         delivered, offenders, ship_units, ship_bits = _integrity_round1(
-            spec, eff_ds, transport, ledger, fault_policy,
-            np.asarray(scorer.masses), backend, params, codec=codec)
+            task, eff_ds, transport, ledger, fault_policy,
+            np.asarray(engine.table(state)), engine.backend, engine.params,
+            codec=codec)
+        units, bits = units + ship_units, bits + ship_bits
         if offenders:
-            eff_ds, alive, degraded = _quarantine(spec, ds, alive, degraded,
+            eff_ds, alive, degraded = _quarantine(task, ds, alive, degraded,
                                                   offenders)
+            # rescore the survivors; their tables already validated clean
             with trace.span("score"):
-                scorer = _build_scorer(eff_ds)  # rescore the survivors
+                state = engine.score(eff_ds)
         elif delivered is not None:
-            # what crossed the wire drives the draw: the lossy codec's
-            # quantized table, or — unverified — a corrupted one
-            scorer = with_masses(scorer, delivered)
-    with trace.span("health"):
-        health = health_from_masses(np.asarray(scorer.masses),
-                                    gram_conds=scorer.gram_conds)
-    _require_positive(scorer.masses)
-    with trace.span("dis"):
-        if pipelined:
-            plan = dis_plan_streamed_batched(scorer, m, probe=probe)
-        else:
-            plan = dis_plan_streamed(scorer, m, probe=probe)
-    if checkpoint is not None:
-        checkpoint.clear()            # the build completed; state is stale
+            # what crossed the wire drives the draw: a lossy codec's
+            # quantized table on the clean path, or — with verification
+            # off — corrupted masses, exactly the undefended blow-up the
+            # integrity benchmark measures
+            state = engine.with_table(state, delivered)
+    plan = engine.draw(state, m)
     if transport is None:
         schedule = _bill_dis(ds.T, m, plan, ledger, r1_payload)
-        return Coreset(plan.indices, plan.weights, schedule.total,
-                       comm_bits=schedule.total_bits, health=health)
-    up_payloads, up_blobs = _round2_wire(plan, alive, ds.T, codec)
-    rep23 = transport.deliver(
-        CommSchedule.dis_rounds23(ds.T, m, counts=np.asarray(plan.counts),
-                                  parties=alive,
-                                  upload_payloads=up_payloads),
-        ledger, max_retries=retries, drop_on_exhaust=False,
-    )
-    indices, r2_units, r2_bits = _ship_round2(
-        transport, ledger, fault_policy, plan, alive, ds.T,
-        codec=codec, blobs=up_blobs)
-    return Coreset(indices, plan.weights,
-                   units1 + rep23.units + ship_units + r2_units,
-                   comm_bits=bits1 + rep23.bits + ship_bits + r2_bits,
+        indices, units, bits = plan.indices, schedule.total, schedule.total_bits
+    else:
+        # rounds 2-3 exhaust hard even under degrade: by now the scores
+        # exist and dropping a party would orphan its drawn rows
+        up_payloads, up_blobs = _round2_wire(plan, alive, ds.T, codec)
+        rep23 = transport.deliver(
+            CommSchedule.dis_rounds23(ds.T, m, counts=np.asarray(plan.counts),
+                                      parties=alive,
+                                      upload_payloads=up_payloads),
+            ledger, max_retries=retries, drop_on_exhaust=False,
+        )
+        indices, r2_units, r2_bits = _ship_round2(
+            transport, ledger, fault_policy, plan, alive, ds.T,
+            codec=codec, blobs=up_blobs)
+        units += rep23.units + r2_units
+        bits += rep23.bits + r2_bits
+    with trace.span("health"):
+        health = engine.health(state)
+    return Coreset(indices, plan.weights, units, comm_bits=bits,
                    degraded=degraded, health=health)
 
 
@@ -892,7 +822,7 @@ def _exec_batched(
     """The batched engine: every (seed, budget) cell in one compiled
     ``jit(vmap(vmap(dis_plan_full)))`` call over the pure DIS core, using
     the ``m_cap`` prefix-masking convention for the budget grid.  For ``m
-    == m_cap`` each cell is exactly the :func:`_exec_materialized` result
+    == m_cap`` each cell is exactly the materialized engine's result
     for that key (eager hoisted totals keep the weight arithmetic
     bit-identical for deterministic-score tasks).
     """
@@ -1056,28 +986,15 @@ class CoresetPipeline:
                     "feature; the materialized engine has no superchunk "
                     "passes to checkpoint"
                 )
-            if cspec.jit:
-                if transport is not None:
-                    raise ValueError(
-                        "the fused jit path cannot deliver per-round "
-                        "schedules through a transport; use the eager "
-                        "materialized engine (jit=False)"
-                    )
-                return _exec_fused(task, self.ds, m, key, ep.backend, ledger,
-                                   cspec.params)
-            return _exec_materialized(task, self.ds, m, key, ep.backend,
-                                      ledger, cspec.params,
-                                      transport=transport,
-                                      fault_policy=cspec.fault_policy,
-                                      codec=ep.codec)
-        return _exec_streaming(
-            task, self.ds, m, key, ep.backend, ledger, probe,
-            cspec.block_size, ep.chunk_blocks, ep.prefetch,
-            pipelined=(ep.engine == "pipelined"),
-            sharded_masses=cspec.sharded_masses, params=cspec.params,
-            transport=transport, fault_policy=cspec.fault_policy,
-            checkpoint=checkpoint, codec=ep.codec,
-        )
+            engine = _MaterializedEngine(task, key, ep.backend, cspec.params)
+        else:
+            engine = _StreamedEngine(
+                task, key, ep.backend, cspec.params, m, probe,
+                cspec.block_size, ep.chunk_blocks, ep.prefetch,
+                pipelined=(ep.engine == "pipelined"),
+                sharded_masses=cspec.sharded_masses, checkpoint=checkpoint)
+        return _build_one(engine, self.ds, m, ledger, transport=transport,
+                          fault_policy=cspec.fault_policy, codec=ep.codec)
 
     def build_failover(
         self,
@@ -1125,9 +1042,7 @@ class CoresetPipeline:
             if engine in tried:
                 continue
             if rung > 0:
-                # recompile on the fallback engine; jit is a
-                # materialized/batched-only flag, never valid on the rungs
-                fb_spec = dataclasses.replace(spec, engine=engine, jit=False)
+                fb_spec = dataclasses.replace(spec, engine=engine)
                 ep = self.plan(fb_spec)
                 if ep.engine in tried:   # pipelined may lower to streamed
                     continue
@@ -1251,29 +1166,6 @@ def build_coreset(
     """
     spec = CoresetSpec(task=task, budgets=int(budget),
                        engine="materialized", backend=backend, params=params)
-    return CoresetPipeline(ds).build(spec, key=key, ledger=ledger)
-
-
-def build_coreset_jit(
-    task: Union[str, CoresetTask],
-    ds: VFLDataset,
-    budget: int,
-    *,
-    key: jax.Array,
-    backend: str = "auto",
-    ledger: Optional[CommLedger] = None,
-    **params,
-) -> Coreset:
-    """One-dispatch :func:`build_coreset` — the materialized engine's fused
-    fast path (shim over ``CoresetSpec(engine="materialized", jit=True)``):
-    scoring + DIS compiled into a single jitted function, cached per
-    ``(task, shapes, backend, params)``.  Weights agree with the eager
-    reference to fp tolerance (whole-program fusion reorders reductions);
-    use :func:`build_coreset` where cross-version draw stability matters.
-    """
-    spec = CoresetSpec(task=task, budgets=int(budget),
-                       engine="materialized", jit=True, backend=backend,
-                       params=params)
     return CoresetPipeline(ds).build(spec, key=key, ledger=ledger)
 
 

@@ -35,7 +35,7 @@ class Coreset:
     continued without every party under ``fault_policy="degrade"`` or
     ``"quarantine"`` — it names the dropped parties/rounds and the widened
     sensitivity bound.  ``health`` (default None: engines that never leave
-    the traced path, e.g. jit/batched) is the
+    the traced path, e.g. batched) is the
     :class:`~repro.core.integrity.HealthReport` of the scoring state the
     draw actually used.
     """
@@ -46,7 +46,7 @@ class Coreset:
     #: Construction cost in wire bits — the packed bytes the codec actually
     #: moved (32 bits/unit on the raw path, measured blob sizes under a
     #: compressed codec, retransmissions included).  0 from engines that
-    #: predate or bypass the bits column (jit/batched cells extracted
+    #: predate or bypass the bits column (batched cells extracted
     #: without a ledger).
     comm_bits: int = 0
     degraded: Optional["DegradedBuild"] = None

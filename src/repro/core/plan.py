@@ -1,9 +1,6 @@
 """CoresetSpec -> ExecutionPlan: the declarative layer over every engine.
 
-After the perf PRs the repo had four divergent build entry points
-(``build_coreset``, ``build_coreset_jit``, ``build_coreset_streaming``,
-``build_coresets_batched``) with inconsistent knobs and validation.  This
-module makes the pipeline spec-compiled, in the declarative-launcher idiom:
+One spec compiles to one engine, in the declarative-launcher idiom:
 
   * :class:`CoresetSpec` — ONE frozen description of a construction: task,
     budgets, seeds, backend, engine preference, streaming knobs
@@ -103,8 +100,8 @@ class CoresetSpec:
     (``num_seeds > 1`` or multiple budgets) compiles to the batched engine.
     ``engine="auto"`` lets the planner choose from the memory model;
     forcing an engine pins the exact legacy code path (the thin shims
-    ``build_coreset`` / ``build_coreset_jit`` / ``build_coreset_streaming``
-    / ``build_coresets_batched`` are precisely such forced specs, and stay
+    ``build_coreset`` / ``build_coreset_streaming`` /
+    ``build_coresets_batched`` are precisely such forced specs, and stay
     draw-identical).  ``params`` carries task-specific score knobs (vkmc's
     ``k``/``alpha``/``local_iters``, vrlr's ``rcond``) verbatim.
 
@@ -120,7 +117,6 @@ class CoresetSpec:
     num_seeds: int = 1
     engine: str = "auto"
     backend: str = "auto"
-    jit: bool = False                     # materialized fast path: one fused dispatch
     block_size: int = 65536
     chunk_blocks: Optional[int] = None    # None -> DEFAULT_CHUNK_BLOCKS (planner)
     prefetch: Optional[bool] = None       # None -> backend-aware (planner)
@@ -163,13 +159,6 @@ class CoresetSpec:
             raise ValueError(
                 f"backend must be 'auto' or one of {SCORE_BACKENDS}, "
                 f"got {self.backend!r}"
-            )
-        if not isinstance(self.jit, bool):
-            raise ValueError(f"jit must be a bool, got {self.jit!r}")
-        if self.jit and self.engine not in ("auto", "materialized", "batched"):
-            raise ValueError(
-                f"jit=True is the materialized/batched fused path; it cannot "
-                f"combine with engine={self.engine!r}"
             )
         if not _is_int(self.block_size) or self.block_size < 1:
             raise ValueError(
@@ -224,13 +213,8 @@ class CoresetSpec:
             raise ValueError(
                 f"codec must be one of {SPEC_CODECS}, got {self.codec!r}"
             )
-        lossy = self.codec not in ("auto", "raw_fp32")
-        if lossy and self.jit:
-            raise ValueError(
-                f"codec={self.codec!r} quantizes the wire; the jit fused "
-                f"path never leaves the device and cannot combine with it"
-            )
-        if lossy and self.engine == "batched":
+        if (self.codec not in ("auto", "raw_fp32")
+                and self.engine == "batched"):
             raise ValueError(
                 f"codec={self.codec!r} quantizes per-round payloads; the "
                 f"batched engine bills its cells lazily and cannot combine "
@@ -450,7 +434,6 @@ class ExecutionPlan:
         spec = self.spec
         lines = [
             f"ExecutionPlan: engine={self.engine}"
-            + (" (jit)" if spec.jit and self.engine == "materialized" else "")
             + (" +sharded_masses" if spec.sharded_masses else ""),
             f"  task={self.task_name} backend={self.backend} "
             f"grid={self.grid[0]}x{self.grid[1]} budgets={spec.budgets} "
@@ -516,7 +499,7 @@ class ExecutionPlan:
 #: knob (fault_policy in PR 7, the integrity policy now) can never
 #: silently alias cached plans.
 PLAN_KEY_FIELDS = (
-    "engine", "backend", "jit", "budgets", "num_seeds", "block_size",
+    "engine", "backend", "budgets", "num_seeds", "block_size",
     "chunk_blocks", "prefetch", "memory_budget_bytes", "sharded_masses",
     "m_cap", "fault_policy", "codec", "comm_budget_bits",
 )
@@ -765,15 +748,9 @@ def _compile_plan(spec: CoresetSpec, ds: VFLDataset) -> ExecutionPlan:
             f"(one full-span superchunk)"
         )
 
-    # spec flags that only make sense on SOME engines must not be dropped
+    # a flag that only makes sense on SOME engines must not be dropped
     # silently when the auto-planner picks another one — mirror the forced
-    # combinations CoresetSpec.__post_init__ already rejects
-    if spec.jit and engine not in ("materialized", "batched"):
-        raise ValueError(
-            f"jit=True is the materialized/batched fused path, but the "
-            f"auto-planner selected engine {engine!r} — drop jit or force "
-            f"a compatible engine"
-        )
+    # combination CoresetSpec.__post_init__ already rejects
     if spec.sharded_masses:
         if engine not in ("streamed", "pipelined"):
             raise ValueError(
@@ -811,13 +788,12 @@ def _compile_plan(spec: CoresetSpec, ds: VFLDataset) -> ExecutionPlan:
     # upper bound, so the prediction is a ceiling the realized bill never
     # crosses.
     cells = n if engine in ("materialized", "batched") else nb
-    lossless_only = spec.jit or engine == "batched"
+    lossless_only = engine == "batched"
     if spec.codec not in ("auto", "raw_fp32") and lossless_only:
         raise ValueError(
             f"codec={spec.codec!r} quantizes per-round payloads, but the "
-            f"planner selected the "
-            f"{'jit fused' if spec.jit else 'batched'} path — use "
-            f"codec='raw_fp32' or a transported engine"
+            f"planner selected the batched path — use codec='raw_fp32' or "
+            f"a transported engine"
         )
 
     def _predict(name: str) -> int:
@@ -836,8 +812,7 @@ def _compile_plan(spec: CoresetSpec, ds: VFLDataset) -> ExecutionPlan:
         if comm_budget_exceeded:
             notes.append(
                 f"comm budget {spec.comm_budget_bits}b unmeetable: the "
-                f"{'jit' if spec.jit else 'batched'} path admits only "
-                f"raw_fp32 ({wire_bits}b predicted)"
+                f"batched path admits only raw_fp32 ({wire_bits}b predicted)"
             )
     else:
         bits_by_codec = {name: _predict(name) for name in CODEC_LADDER}
@@ -848,10 +823,10 @@ def _compile_plan(spec: CoresetSpec, ds: VFLDataset) -> ExecutionPlan:
         if codec_note:
             notes.append(codec_note)
 
-    # failover ladder: the cheaper engines after the chosen one.  jit and
-    # sharded_masses bind the spec to specific engines (validated above), so
-    # those plans pin their engine and never failover.
-    if engine in FAILOVER_LADDER and not spec.jit and not spec.sharded_masses:
+    # failover ladder: the cheaper engines after the chosen one.
+    # sharded_masses binds the spec to the streaming engines (validated
+    # above), so those plans pin their engine and never failover.
+    if engine in FAILOVER_LADDER and not spec.sharded_masses:
         fallback = FAILOVER_LADDER[FAILOVER_LADDER.index(engine) + 1:]
     else:
         fallback = ()

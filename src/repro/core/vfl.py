@@ -110,9 +110,10 @@ class VFLDataset:
     def _validate_values(self) -> None:
         """NaN/Inf screen: a single non-finite cell poisons every Gram /
         distance it touches downstream, so fail loudly at ingest and name
-        the offender.  Skipped for traced arrays (``_exec_fused`` constructs
-        datasets inside jit) and via ``validate=False`` when non-finite
-        values are intentional (e.g. corruption-injection tests)."""
+        the offender.  Skipped for traced arrays (a dataset built inside a
+        jitted function has no values to read) and via ``validate=False``
+        when non-finite values are intentional (e.g. corruption-injection
+        tests)."""
         named = [(f"party {j}", p) for j, p in enumerate(self.parts)]
         if self.y is not None:
             named.append((f"labels (party {self.T - 1})", self.y))

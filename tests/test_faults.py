@@ -166,7 +166,7 @@ def test_transport_stats_accumulate_across_schedules():
 
 
 @pytest.mark.parametrize("engine", ["materialized", "streamed", "pipelined"])
-@pytest.mark.parametrize("task", ["vrlr", "vkmc"])
+@pytest.mark.parametrize("task", ["vrlr", "vkmc", "uniform"])
 def test_fault_free_transport_pins_bit_identical(engine, task):
     ds = _ds(labels=task == "vrlr")
     key = jax.random.PRNGKey(5)
@@ -267,11 +267,12 @@ def test_chaos_replay_property():
 # -- degraded builds ---------------------------------------------------------
 
 
-def test_degrade_drops_party_and_issues_receipt():
+@pytest.mark.parametrize("engine", ["materialized", "streamed", "pipelined"])
+def test_degrade_drops_party_and_issues_receipt(engine):
     ds = _ds()
     tr = Transport(FaultPlan(seed=0, drop={0: 1.0}, max_retries=2))
     led = CommLedger()
-    cs = CoresetPipeline(ds).build(_spec(policy="degrade"),
+    cs = CoresetPipeline(ds).build(_spec(engine, policy="degrade"),
                                    key=jax.random.PRNGKey(3),
                                    ledger=led, transport=tr)
     d = cs.degraded
@@ -337,9 +338,6 @@ def test_build_rejects_incompatible_combinations():
                                          "streamed/pipelined-engine"):
         pipe.build(_spec("materialized"), key=key,
                    checkpoint=StreamCheckpoint())
-    with pytest.raises(ValueError, match="fused jit path"):
-        pipe.build(_spec("materialized", jit=True), key=key,
-                   transport=Transport())
 
 
 # -- checkpointed resume -----------------------------------------------------

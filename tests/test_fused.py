@@ -1,4 +1,4 @@
-"""Fused single-pass kernel + one-dispatch construction path.
+"""Fused single-pass kernel, batch-safe kernels and the stacked party view.
 
 Covers the perf_opt acceptance criteria:
   * ``kmeans_assign_update`` (Pallas, interpret on CPU) matches the
@@ -11,8 +11,6 @@ Covers the perf_opt acceptance criteria:
     the per-slice results;
   * ``build_coresets_batched`` runs with ``backend="pallas"`` and matches
     the ``ref`` backend numerically;
-  * ``build_coreset_jit`` (scoring + DIS in ONE jitted dispatch) reproduces
-    the sequential ``build_coreset`` for the same key;
   * the stacked party view pads/masks correctly.
 """
 
@@ -24,8 +22,6 @@ import pytest
 from benchmarks.fused_lloyd import count_primitives, structural_passes
 from repro.core import (
     VFLDataset,
-    build_coreset,
-    build_coreset_jit,
     build_coresets_batched,
 )
 from repro.core.vkmc import kmeans, lloyd
@@ -222,33 +218,8 @@ def test_stacked_view_appends_labels():
 
 
 # --------------------------------------------------------------------------
-# One-dispatch construction paths
+# Batched construction on the kernels
 # --------------------------------------------------------------------------
-
-@pytest.mark.parametrize("task,kw", [
-    ("vrlr", {}), ("vkmc", {"k": 3, "local_iters": 3}), ("uniform", {})])
-def test_build_coreset_jit_matches_sequential(task, kw):
-    ds = _dataset(jax.random.PRNGKey(11))
-    for seed in (0, 1):
-        key = jax.random.PRNGKey(20 + seed)
-        seq = build_coreset(task, ds, 50, key=key, backend="ref", **kw)
-        fast = build_coreset_jit(task, ds, 50, key=key, backend="ref", **kw)
-        np.testing.assert_array_equal(np.asarray(seq.indices), np.asarray(fast.indices))
-        np.testing.assert_allclose(np.asarray(seq.weights), np.asarray(fast.weights),
-                                   rtol=1e-6)
-        assert seq.comm_units == fast.comm_units
-
-
-def test_build_coreset_jit_caches_compilation():
-    from repro.core.api import _JIT_BUILDERS
-    ds = _dataset(jax.random.PRNGKey(12))
-    build_coreset_jit("vrlr", ds, 30, key=jax.random.PRNGKey(0), backend="ref")
-    size0 = len(_JIT_BUILDERS)
-    build_coreset_jit("vrlr", ds, 30, key=jax.random.PRNGKey(1), backend="ref")
-    assert len(_JIT_BUILDERS) == size0          # same geometry -> cache hit
-    build_coreset_jit("vrlr", ds, 31, key=jax.random.PRNGKey(2), backend="ref")
-    assert len(_JIT_BUILDERS) == size0 + 1      # new budget -> new entry
-
 
 @pytest.mark.parametrize("task,kw", [
     ("vrlr", {}), ("vkmc", {"k": 3, "local_iters": 2})])

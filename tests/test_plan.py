@@ -28,7 +28,6 @@ from repro.core import (
     CoresetSpec,
     VFLDataset,
     build_coreset,
-    build_coreset_jit,
     build_coreset_streaming,
     build_coresets_batched,
     compile_plan,
@@ -88,8 +87,6 @@ def test_spec_validates_enums_and_flags():
         CoresetSpec(prefetch=1)
     with pytest.raises(ValueError, match="memory_budget_bytes"):
         CoresetSpec(memory_budget_bytes=0)
-    with pytest.raises(ValueError, match="jit"):
-        CoresetSpec(jit=True, engine="streamed")
     with pytest.raises(ValueError, match="sharded_masses"):
         CoresetSpec(sharded_masses=True, engine="materialized")
     with pytest.raises(ValueError, match="task"):
@@ -261,16 +258,6 @@ def test_forced_materialized_matches_build_coreset():
         assert led_a.by_tag() == led_b.by_tag()
 
 
-def test_forced_jit_matches_build_coreset_jit():
-    ds = _dataset(jax.random.PRNGKey(14), n=400)
-    key = jax.random.PRNGKey(15)
-    legacy = build_coreset_jit("vrlr", ds, 30, key=key)
-    forced = CoresetPipeline(ds).build(
-        CoresetSpec(task="vrlr", budgets=30, engine="materialized", jit=True),
-        key=key)
-    _same_coreset(legacy, forced)
-
-
 def test_forced_streaming_matches_build_coreset_streaming():
     ds = _dataset(jax.random.PRNGKey(16), n=1100, numpy_backed=True)
     key = jax.random.PRNGKey(17)
@@ -419,19 +406,6 @@ def test_sharded_masses_misaligned_grid_fails_at_plan_time():
 # --------------------------------------------------------------------------
 # 6: spec flags never dropped silently; stale plans rejected
 # --------------------------------------------------------------------------
-
-def test_auto_planner_never_drops_jit_silently():
-    """jit=True with engine='auto' must not be ignored when the memory
-    model picks a streaming engine — same rejection as the forced combo."""
-    ds = _dataset(jax.random.PRNGKey(30), n=4096)
-    spec = CoresetSpec(task="vrlr", budgets=32, jit=True, block_size=256,
-                       chunk_blocks=2, memory_budget_bytes=1)
-    with pytest.raises(ValueError, match="jit"):
-        CoresetPipeline(ds).plan(spec)
-    # with a loose budget the fused materialized path plans fine
-    loose = spec.replace(memory_budget_bytes=1 << 30)
-    assert CoresetPipeline(ds).plan(loose).engine == "materialized"
-
 
 def test_auto_planner_never_drops_sharded_masses_silently():
     ds = _dataset(jax.random.PRNGKey(31), n=800)

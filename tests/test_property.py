@@ -11,7 +11,7 @@ from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.core.comm import CommLedger, theoretical_dis_cost
-from repro.core.dis import dis_sample
+from repro.core.dis import dis_marginals, dis_sample
 from repro.core.selector import SelectorConfig, local_scores, sample_coreset
 from repro.core.vfl import split_columns
 from repro.sharding.specs import MESH_SIZES, sanitize

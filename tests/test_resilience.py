@@ -205,10 +205,6 @@ def test_fallback_chain_follows_ladder():
     assert chains["pipelined"] == ("streamed",)
     assert chains["streamed"] == ()
     assert chains["batched"] == ()
-    # jit pins the engine — no ladder
-    jspec = CoresetSpec(task="vrlr", budgets=16, engine="materialized",
-                        block_size=64, jit=True)
-    assert compile_plan(jspec, ds).fallback_chain == ()
     assert FAILOVER_LADDER == ("materialized", "pipelined", "streamed")
 
 

@@ -171,8 +171,6 @@ def test_spec_codec_validation():
     for bad in ("gzip", "delta_varint"):  # not a spec-selectable table format
         with pytest.raises(ValueError):
             CoresetSpec(task="vrlr", budgets=32, codec=bad)
-    with pytest.raises(ValueError, match="jit"):
-        CoresetSpec(task="vrlr", budgets=32, codec="fp16", jit=True)
     with pytest.raises(ValueError, match="batched"):
         CoresetSpec(task="vrlr", budgets=32, codec="int8_blockscale",
                     engine="batched")

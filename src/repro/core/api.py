@@ -52,6 +52,7 @@ from repro.core.dis import (
     _float_dtype,
     dis_plan_compiled,
     dis_plan_full,
+    round2_rows,
     split_uploads,
     uniform_plan,
 )
@@ -493,10 +494,8 @@ def _require_positive(totals) -> None:
 def _bill_dis(T: int, m: int, plan, ledger: Optional[CommLedger],
               round1_payload: WirePayload) -> CommSchedule:
     """Record the DIS bill of a realised plan (the transportless path)."""
-    with trace.span("wait", of="counts"):
-        counts = np.asarray(plan.counts)
     with trace.span("bill"):
-        schedule = CommSchedule.dis(T, m, counts=counts,
+        schedule = CommSchedule.dis(T, m, counts=np.asarray(plan.counts),
                                     round1_payload=round1_payload)
         schedule.record(ledger)
     return schedule
@@ -550,7 +549,9 @@ class _Engine:
 class _MaterializedEngine(_Engine):
     """Per-row scores: the task's (T, n) score table, drawn from by ONE
     compiled dispatch (:func:`dis_plan_compiled`, bit-identical to an eager
-    :func:`dis_plan_full`).  The state is ``(scores, dis_key)``."""
+    :func:`dis_plan_full`).  The state is ``(scores, dis_key)``.  The draw
+    returns its counts on the host, read inside ``repro.dis`` so the span
+    carries ``draw_rows`` (:func:`repro.core.dis.round2_rows`)."""
 
     def cells(self, ds: VFLDataset) -> int:
         return ds.n
@@ -569,8 +570,12 @@ class _MaterializedEngine(_Engine):
         scores, dis_key = state
         with trace.span("dis"):
             plan = dis_plan_compiled(dis_key, scores, m, core=dis_plan_full)
+            with trace.span("wait", of="counts"):
+                counts = np.asarray(plan.counts)
+            trace.add(draw_rows=round2_rows(dis_key, counts, scores.shape[1],
+                                            m))
         _require_positive(plan.totals)
-        return plan
+        return plan._replace(counts=counts)
 
     def health(self, state) -> HealthReport:
         return health_from_masses(np.asarray(state[0]))

@@ -99,13 +99,33 @@ def _gumbel_from_bits(bits, rows: int, n: int):
     return -jnp.log(-jnp.log(u)).reshape(rows, n)
 
 
+def _partitionable_rows(key_data, lg, first, rows: int):
+    """Rows ``[first, first + rows)`` of ``jax.random.categorical(key, lg,
+    shape=(cap,))`` in the partitionable threefry layout (JAX's default),
+    for any ``cap`` past them.  There the uniform bits at flat position p
+    of the draw are ``threefry_2x32(key, (0, p))`` xor-folded, a function
+    of p alone, so these rows come from counters ``[first*n, (first +
+    rows)*n)`` and nothing else.  ``first`` is a uint32 row index."""
+    n = lg.shape[-1]
+    lo = jax.lax.iota(jnp.uint32, rows * n) + first * np.uint32(n)
+    # key words batched as the counters are: under nested vmaps the chunk
+    # index can be batched over an axis the key is not, and threefry's
+    # rolled-loop lowering (the CPU's) needs key words of equal shapes
+    k1, k2 = (lo * np.uint32(0) + k for k in key_data)
+    b1, b2 = _threefry2x32_p.bind(k1, k2, jnp.zeros_like(lo), lo)
+    return jnp.argmax(_gumbel_from_bits(b1 ^ b2, rows, n) + lg[None, :],
+                      axis=-1)
+
+
 def _categorical_head(key_data, lg, cap: int, take: int):
     """The first ``take`` entries of ``jax.random.categorical(key, lg,
     shape=(cap,))`` WITHOUT materializing the (cap, bs) gumbel tensor.
 
     Every DIS round-2 sampler in this codebase follows the full-capacity
     candidate-stream convention — draw ``cap`` iid candidates per cell, use
-    the first a_c — because static shapes demand it inside jit/vmap.  The
+    the first a_c — because static shapes demand it inside jit/vmap.  In
+    the partitionable layout the head is the plain prefix of the stream
+    (:func:`_partitionable_rows` from row 0).  In the legacy layout the
     full draw's uniform bits come from ``threefry_2x32(key, iota(cap*bs))``,
     which pairs counter p with counter p + cap*bs/2 and keeps lane 1 for
     flat positions below the midpoint — so rows [0, take) (flat positions
@@ -115,6 +135,8 @@ def _categorical_head(key_data, lg, cap: int, take: int):
     uses a_c of its cap candidates only ever *computes* max(a_c) rows
     (:func:`repro.core.streaming.dis_plan_streamed_batched`).
     """
+    if getattr(jax.config, "jax_threefry_partitionable", False):
+        return _partitionable_rows(key_data, lg, np.uint32(0), take)
     bs = lg.shape[-1]
     half = (cap * bs) // 2
     x1 = jax.lax.iota(jnp.uint32, take * bs)
@@ -123,70 +145,110 @@ def _categorical_head(key_data, lg, cap: int, take: int):
     return jnp.argmax(_gumbel_from_bits(bits, take, bs) + lg[None, :], axis=-1)
 
 
-#: Per-party gumbel bytes above which :func:`_categorical_rows` draws the
-#: round-2 candidates in row chunks (the paper's YearPredictionMSD at
-#: m=2048 would otherwise hold 4.2 GB of noise per party).  Read at trace
-#: time; :func:`dis_plan_compiled` keys its cache on it.
+#: Per-party gumbel bytes above which round 2 draws its candidates in
+#: row chunks (the paper's YearPredictionMSD at m=2048 would otherwise
+#: hold 4.2 GB of noise per party).  Read at trace time;
+#: :func:`dis_plan_compiled` keys its cache on it.
 GUMBEL_CHUNK_BYTES = 1 << 28
 
 
-def _threefry_key_data(key):
-    """The raw (2,) uint32 threefry key behind ``key``, or None when the
-    key is of another PRNG implementation."""
+def _is_threefry(key) -> bool:
+    """True when ``key`` (typed, or raw under the default implementation)
+    is a threefry key."""
     if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
-        if "threefry" not in str(jax.random.key_impl(key)).lower():
-            return None
-        return jax.random.key_data(key)
-    if getattr(jax.config, "jax_default_prng_impl",
-               "threefry2x32") != "threefry2x32":
+        return "threefry" in str(jax.random.key_impl(key)).lower()
+    return getattr(jax.config, "jax_default_prng_impl",
+                   "threefry2x32") == "threefry2x32"
+
+
+def _threefry_key_data(key):
+    """The raw (..., 2) uint32 threefry key data behind ``key``, or None
+    when the key is of another PRNG implementation."""
+    if not _is_threefry(key):
         return None
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        return jax.random.key_data(key)
     return key
 
 
-def _categorical_rows(key, lg, cap: int):
-    """``jax.random.categorical(key, lg, shape=(cap,))``, bit for bit, with
-    the (cap, n) gumbel tensor drawn in row chunks of at most
-    :data:`GUMBEL_CHUNK_BYTES` instead of whole.
-
-    In the partitionable threefry layout (JAX's default) the uniform bits
-    at flat position p of the draw are ``threefry_2x32(key, (0, p))``
-    xor-folded, a function of p alone; so rows [r0, r1) come from counters
-    [r0*n, r1*n), and each row's argmax is unchanged.  Any other layout,
-    dtype or key implementation takes the whole draw.
-    """
-    n = lg.shape[-1]
-    rows = max(1, GUMBEL_CHUNK_BYTES // (4 * n))
-    nchunks = -(-cap // rows)
-    kd = _threefry_key_data(key)
-    if (rows >= cap or kd is None or _threefry2x32_p is None
+def _chunk_rows(key, n: int, cap: int) -> Optional[int]:
+    """Rows per chunk of round 2's partial draw over ``n`` logits, or None
+    where every party draws its whole ``cap`` stream: any layout, dtype or
+    key implementation but partitionable float32 threefry, an empty
+    capacity, or a stream past 2**32 counters."""
+    if (cap <= 0 or _threefry2x32_p is None or not _is_threefry(key)
             or _float_dtype() != jnp.float32
-            or not getattr(jax.config, "jax_threefry_partitionable", False)
-            or nchunks * rows * n >= 2 ** 32):
-        return jax.random.categorical(key, lg, shape=(cap,))
+            or not getattr(jax.config, "jax_threefry_partitionable", False)):
+        return None
+    rows = min(cap, max(1, GUMBEL_CHUNK_BYTES // (4 * n)))
+    if -(-cap // rows) * rows * n >= 2 ** 32:
+        return None
+    return rows
 
-    def chunk(c):
-        lo = jax.lax.iota(jnp.uint32, rows * n) + c * np.uint32(rows * n)
-        b1, b2 = _threefry2x32_p.bind(kd[0], kd[1], jnp.zeros_like(lo), lo)
-        return jnp.argmax(_gumbel_from_bits(b1 ^ b2, rows, n) + lg[None, :],
-                          axis=-1)
 
-    draws = jax.lax.map(chunk, jnp.arange(nchunks, dtype=jnp.uint32))
-    return draws.reshape(-1)[:cap]
+def _round2_candidates(keys, logits, counts, cap: int):
+    """Round 2's (T, cap) candidate streams: entry r < ``counts[j]`` of
+    row j is entry r of ``jax.random.categorical(keys[j], logits[j],
+    shape=(cap,))``, bit for bit.  The entries past ``counts[j]``, which
+    round 2 never takes, are unspecified.
+
+    Where :func:`_chunk_rows` allows, party j computes only its first
+    ceil(a_j / rows) chunks of ``rows`` rows (:func:`_partitionable_rows`),
+    a loop whose trip count is the realised a_j; the parties run one after
+    another (``lax.map``), since a ``vmap`` would run every party to the
+    largest a_j.  Under an outer ``vmap`` the loop runs to the batch's
+    largest a_j.  Otherwise every party draws its whole stream.
+    """
+    n = logits.shape[-1]
+    rows = _chunk_rows(keys, n, cap)
+    if rows is None:
+        return jax.vmap(
+            lambda k, lg: jax.random.categorical(k, lg, shape=(cap,))
+        )(keys, logits)
+    nchunks = -(-cap // rows)
+
+    def party(args):
+        kd, lg, a_j = args
+
+        def chunk(c, buf):
+            first = (c * rows).astype(jnp.uint32)
+            return jax.lax.dynamic_update_slice(
+                buf, _partitionable_rows(kd, lg, first, rows), (c * rows,))
+
+        return jax.lax.fori_loop(0, (a_j + rows - 1) // rows, chunk,
+                                 jnp.zeros((nchunks * rows,), jnp.int32))
+
+    cand = jax.lax.map(party, (_threefry_key_data(keys), logits, counts))
+    return cand[:, :cap]
+
+
+def round2_rows(key, counts, n: int, cap: int) -> int:
+    """The candidate rows :func:`dis_plan_full`'s round 2 computes for the
+    realised host ``counts`` a_j over ``n`` logits at capacity ``cap``:
+    sum_j ceil(a_j / rows) * rows in the partial draw, T * cap where every
+    party draws its whole stream.  Reads the flags as the draw's trace
+    does."""
+    rows = _chunk_rows(key, n, cap)
+    if rows is None:
+        return len(counts) * cap
+    return sum(-(-int(a) // rows) for a in counts) * rows
 
 
 def _head_draws_ok(subs, cap: int, bs: int, take: int) -> bool:
     """True when :func:`_categorical_head` provably replays the full draw:
-    float32 sampling dtype, even counter stream, head strictly inside the
-    first threefry lane, and non-partitionable threefry keys (the layouts
-    the replay assumes).  Anything else falls back to the full-capacity
-    draw — still one dispatch per group, just cap rows instead of take."""
+    float32 sampling dtype, threefry keys, and in the partitionable layout
+    any ``0 < take <= cap``; in the legacy layout an even counter stream
+    and a head strictly inside the first threefry lane (take <= cap // 2).
+    Anything else falls back to the full-capacity draw — still one
+    dispatch per group, just cap rows instead of take."""
     if _threefry2x32_p is None or _float_dtype() != jnp.float32:
         return False
-    if cap <= 0 or take > cap // 2 or (cap * bs) % 2:
-        return False
     if getattr(jax.config, "jax_threefry_partitionable", False):
+        if not 0 < take <= cap or take * bs >= 2 ** 32:
+            return False
+    elif cap <= 0 or take > cap // 2 or (cap * bs) % 2:
         return False
-    return _threefry_key_data(subs) is not None
+    return _is_threefry(subs)
 
 
 class DisPlan(NamedTuple):
@@ -253,14 +315,13 @@ def dis_plan_full(
 
     # ---- round 2: party-local index sampling, then server union -------------
     # Party j draws a_j iid indices ~ g_i^(j)/G^(j).  To keep everything
-    # static-shape we draw `cap` candidates per party and select the first
-    # a_j of each via a mask when concatenating — statistically identical
-    # because draws are iid.
+    # static-shape each party has a `cap`-candidate stream and the first a_j
+    # of each are selected via a mask when concatenating — statistically
+    # identical because draws are iid; only those first a_j are computed
+    # (rounded up to whole chunks) where the PRNG layout allows.
     with jax.named_scope("dis_round2"):
         logits = jnp.log(jnp.maximum(scores, 1e-30))           # (T, n)
-        cand = jax.vmap(
-            lambda k, lg: _categorical_rows(k, lg, cap)
-        )(subs[1:], logits)                                    # (T, cap)
+        cand = _round2_candidates(subs[1:], logits, a, cap)    # (T, cap)
         take = jnp.arange(cap)[None, :] < a[:, None]           # (T, cap) bool
         # stable selection of exactly m entries (sum(a) = m by construction)
         order = jnp.argsort(~take.reshape(-1), stable=True)    # taken slots first
@@ -284,7 +345,7 @@ def dis_plan_full(
 
 def _dis_core(core, key, scores, totals, m, chunk_bytes):
     """The body :func:`dis_plan_compiled` jits.  ``chunk_bytes`` only keys
-    the cache: :func:`_categorical_rows` reads :data:`GUMBEL_CHUNK_BYTES`
+    the cache: :func:`_round2_candidates` reads :data:`GUMBEL_CHUNK_BYTES`
     while this traces.  ``dis_traces`` counts the traces on the open span,
     so a warm build's ``repro.dis`` carries none."""
     del chunk_bytes

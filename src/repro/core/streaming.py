@@ -766,6 +766,7 @@ def dis_plan_streamed(
             per_cell[c] = (b * bs + cand, g_b[cand])
         del sc_b, g_b
         probe()
+    trace.add(draw_rows=len(per_cell) * cap)     # a whole stream per cell
     # server union in cell order — matches the in-memory plan's stable
     # taken-slots-first selection exactly
     cells = sorted(per_cell)
@@ -904,6 +905,7 @@ def dis_plan_streamed_batched(
             take_eff, head = take, True
         else:
             take_eff, head = min(take_pow2, cap), False
+        trace.add(draw_rows=pad_nc * (take_eff if head else cap))
         rows, gath = _group_candidates(
             sc_g, subs, jnp.asarray(cells), jnp.asarray(gidx),
             jnp.asarray(jidx), jnp.asarray(bids), n,

@@ -183,30 +183,47 @@ def test_batched_redraw_touched_block_edges():
     _assert_plans_equal(dis_plan_streamed(legacy, m), plan)
 
 
-def test_head_draw_replay_matches_full_categorical():
+@pytest.mark.parametrize("partitionable", [True, False],
+                         ids=["partitionable", "legacy"])
+def test_head_draw_replay_matches_full_categorical(partitionable):
     """_categorical_head reproduces the first rows of the full-capacity
-    categorical stream bit for bit across shapes, keys, and -inf padding."""
-    for trial in range(8):
-        k = jax.random.PRNGKey(100 + trial)
-        bs = [4096, 128, 500, 64][trial % 4]
-        cap = [512, 90, 34, 8][trial % 4]
-        take = min(cap // 2, [5, 3, 16, 4][trial % 4])
-        lg = jnp.log(jax.random.uniform(jax.random.fold_in(k, 1), (bs,))
-                     + 1e-3).astype(jnp.float32)
-        if trial % 2:                     # padded-row logits
-            lg = jnp.where(jnp.arange(bs) < bs - 7, lg, -jnp.inf)
-        assert _head_draws_ok(jnp.stack([k, k]), cap, bs, take)
-        full = np.asarray(jax.random.categorical(k, lg, shape=(cap,)))[:take]
-        head = np.asarray(_categorical_head(k, lg, cap, take))
-        np.testing.assert_array_equal(full, head)
+    categorical stream bit for bit across shapes, keys, and -inf padding,
+    in both threefry layouts: any head up to cap in the partitionable one,
+    heads up to cap // 2 in the legacy one."""
+    with jax.threefry_partitionable(partitionable):
+        for trial in range(8):
+            k = jax.random.PRNGKey(100 + trial)
+            bs = [4096, 128, 500, 64][trial % 4]
+            cap = [512, 90, 34, 8][trial % 4]
+            take = [5, 3, 16, 4][trial % 4]
+            if partitionable and trial >= 4:
+                take = [512, 61, 34, 7][trial % 4]    # past cap // 2
+            take = take if partitionable else min(cap // 2, take)
+            lg = jnp.log(jax.random.uniform(jax.random.fold_in(k, 1), (bs,))
+                         + 1e-3).astype(jnp.float32)
+            if trial % 2:                     # padded-row logits
+                lg = jnp.where(jnp.arange(bs) < bs - 7, lg, -jnp.inf)
+            assert _head_draws_ok(jnp.stack([k, k]), cap, bs, take)
+            full = np.asarray(jax.random.categorical(k, lg, shape=(cap,)))
+            head = np.asarray(_categorical_head(k, lg, cap, take))
+            np.testing.assert_array_equal(full[:take], head)
 
 
 def test_head_draws_gate():
     keys = jnp.stack([jax.random.PRNGKey(0)] * 3)
+    with jax.threefry_partitionable(False):
+        assert _head_draws_ok(keys, 512, 4096, 5)
+        assert not _head_draws_ok(keys, 512, 4096, 300)    # take > cap // 2
+        assert not _head_draws_ok(keys, 0, 4096, 0)        # empty capacity
+        assert not _head_draws_ok(keys, 3, 7, 1)           # odd counter stream
+    # the partitionable layout: the head is a plain prefix of the stream
     assert _head_draws_ok(keys, 512, 4096, 5)
-    assert not _head_draws_ok(keys, 512, 4096, 300)    # take > cap // 2
-    assert not _head_draws_ok(keys, 0, 4096, 0)        # empty capacity
-    assert not _head_draws_ok(keys, 3, 7, 1)           # odd counter stream
+    assert _head_draws_ok(keys, 512, 4096, 300)
+    assert _head_draws_ok(keys, 512, 4096, 512)
+    assert _head_draws_ok(keys, 3, 7, 1)
+    assert not _head_draws_ok(keys, 512, 4096, 513)        # take > cap
+    assert not _head_draws_ok(keys, 512, 4096, 0)          # empty head
+    assert not _head_draws_ok(keys, 0, 4096, 0)            # empty capacity
 
 
 # --------------------------------------------------------------------------

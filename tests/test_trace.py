@@ -142,6 +142,60 @@ def test_pipelined_build_span_tree(tmp_path):
         assert len(_children(thread, b, name)) == 1, name
 
 
+def _pipelined_rows(indices, counts, m, nb, bs, chunk_blocks):
+    """Rows the grouped redraw computes from a realised plan: per group of
+    ``chunk_blocks`` touched blocks, its occupied cells padded to a multiple
+    of 8 times its longest head, bucketed to a power of two up to m."""
+    a_cells = {}
+    for j, rows in enumerate(np.split(indices, np.cumsum(counts)[:-1])):
+        for b in rows // bs:
+            a_cells[(j, int(b))] = a_cells.get((j, int(b)), 0) + 1
+    touched = sorted({b for _, b in a_cells})
+    total = 0
+    for g0 in range(0, len(touched), chunk_blocks):
+        group = set(touched[g0:g0 + chunk_blocks])
+        heads = [a for (j, b), a in a_cells.items() if b in group]
+        take = max(heads)
+        pow2 = 1 << (take - 1).bit_length()
+        total += -(-len(heads) // 8) * 8 * (pow2 if pow2 <= m else take)
+    return total
+
+
+@pytest.mark.parametrize("engine", ["materialized", "pipelined"])
+def test_dis_span_counts_the_round2_rows(engine, tmp_path, monkeypatch):
+    """``draw_rows`` on ``repro.dis`` is the candidate rows round 2
+    computed: sum_j ceil(a_j / rows) * rows on the materialized engine, at
+    most (ceil(m / rows) + T - 1) * rows against T * m whole-stream rows;
+    the grouped head draws' cells times their head on the pipelined one."""
+    from repro.core import dis
+
+    m, rows = 32, 5
+    monkeypatch.setattr(dis, "GUMBEL_CHUNK_BYTES", 4 * N * rows)
+    ds = _data(host=engine != "materialized")
+    spec = CoresetSpec(task="vrlr", budgets=m, engine=engine, backend="ref",
+                       block_size=1024, chunk_blocks=2)
+    pipe = CoresetPipeline(ds)
+    keys = jax.random.split(jax.random.PRNGKey(14), 2)
+    pipe.build(pipe.plan(spec), key=keys[0])                 # warm
+    led = CommLedger()
+    with jax.profiler.trace(str(tmp_path)):
+        cs = pipe.build(pipe.plan(spec), key=keys[1], ledger=led)
+        indices = np.asarray(cs.indices)
+    counts = [msg.units for msg in led.messages
+              if msg.tag == "dis/round2/S_up"]
+    assert len(counts) == ds.T and sum(counts) == m
+    (thread,) = _spans(tmp_path)
+    (d,) = [e for e in thread if e[0] == "repro.dis"]
+    if engine == "materialized":
+        want = sum(-(-a // rows) for a in counts) * rows
+        assert d[3]["draw_rows"] == want
+        assert want <= (-(-m // rows) + ds.T - 1) * rows < ds.T * m
+    else:
+        assert d[3]["draw_rows"] == _pipelined_rows(indices, counts, m,
+                                                    nb=3, bs=1024,
+                                                    chunk_blocks=2)
+
+
 def test_forced_recompile_is_marked_inside_its_span(tmp_path):
     f = jax.jit(lambda x: jnp.cos(x) * 3.0 + 1.0)
     with jax.profiler.trace(str(tmp_path)):
